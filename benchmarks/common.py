@@ -30,6 +30,9 @@ PROD_SPACE = HDSpace(dim=40960, ngram=16, z_threshold=5.0)
 # CPU-sized space used by the software benchmarks (keeps run.py < minutes).
 BENCH_SPACE = HDSpace(dim=8192, ngram=16, z_threshold=5.0)
 
+# Illumina read length of every synthetic benchmark sample.
+READ_LEN = synth.CommunitySpec.read_len
+
 # The same two setups as full profiling configs (window/batch/backend named).
 PROD_CONFIG = ProfilerConfig(space=PROD_SPACE, window=8192, batch_size=4096,
                              backend="pallas_matmul")

@@ -82,7 +82,8 @@ def prototype_stream(db, *, batch: int = 64, bb: int = 8,
     from repro.kernels.ops import fused_tile_plan
     s, w = (int(x) for x in db.prototypes.shape)
     dim = w * 32
-    plan = fused_tile_plan(batch, s, w, bb=bb)
+    plan = fused_tile_plan(batch, s, w, read_len=common.READ_LEN,
+                           n=common.BENCH_SPACE.ngram, bb=bb)
     rows = {
         "matmul_pm1_bf16": s * dim * 2 / batch,
         "fused_packed_per_tile":

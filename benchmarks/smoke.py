@@ -153,7 +153,8 @@ def prototype_bytes_per_read(backend: str, space: HDSpace,
     if backend == "pallas_packed":
         return pad128 * w_bytes * (-(-batch // 8)) / batch
     if backend == "pallas_fused":
-        plan = fused_tile_plan(batch, num_prototypes, space.num_words)
+        plan = fused_tile_plan(batch, num_prototypes, space.num_words,
+                               read_len=common.READ_LEN, n=space.ngram)
         return plan["proto_bytes_per_call"] / batch
     raise ValueError(f"no prototype-stream model for backend {backend!r}")
 

@@ -90,10 +90,9 @@ def pipelined_apply(stage_params, x: jax.Array, stage_fn: Callable,
         mask = (stage == n_stages - 1).astype(outputs.dtype)
         return jax.lax.psum(outputs * mask, axis)
 
-    from repro.distributed.sharding import shard_map_compat
     specs_params = jax.tree.map(lambda _: P(axis), stage_params)
-    out = shard_map_compat(
+    out = jax.shard_map(
         per_pod, mesh=mesh,
-        in_specs=(specs_params, P()), out_specs=P(),
+        in_specs=(specs_params, P()), out_specs=P(), check_vma=False,
     )(stage_params, micro)
     return out.reshape(b, *x.shape[1:])

@@ -26,11 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.6 moved it to the top level
-    from jax import shard_map as _shard_map_raw  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
 _state = threading.local()
 
 Rules = dict[str, tuple[str, ...] | str | None]
@@ -80,40 +75,6 @@ PROFILE_RULES: Rules = {
     "hd_words": None,         # packed HD dim: contiguous within a shard
     "species": None,          # per-species scores: replicated after merge
 }
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax spellings.
-
-    Pallas kernels have no replication rule, so the check must be
-    disabled for Pallas-based shard bodies; the flag is ``check_vma`` on
-    current jax and ``check_rep`` on older releases.  Import location
-    (``jax.shard_map`` vs ``jax.experimental.shard_map``) is handled at
-    module import.  Used by :mod:`repro.pipeline.sharded` and the
-    multi-device mesh tests.
-    """
-    for flag in ("check_vma", "check_rep"):
-        try:
-            return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **{flag: False})
-        except TypeError:
-            continue
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across its two constructor spellings.
-
-    Current jax takes ``(axis_sizes, axis_names)``; jax <= 0.4.x takes a
-    single ``((name, size), ...)`` shape tuple.  Device-free: resolves
-    sharding rules without any real mesh (used by ``tests/``).
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 
 def make_profile_mesh(num_shards: int | None = None) -> Mesh:

@@ -38,11 +38,6 @@ from repro.core import item_memory
 from repro.core.hd_space import HDSpace
 from repro.kernels import ops
 
-#: VMEM bytes the feasibility filter budgets per config.  TPU cores have
-#: ~16 MiB; the margin leaves room for the compiler's own double-buffered
-#: staging of the small pipelined operands.
-VMEM_BUDGET = 12 * 2 ** 20
-
 #: Default on-disk cache (see module docstring for overrides).
 DEFAULT_CACHE = Path("~/.cache/repro/autotune.json")
 
@@ -92,37 +87,17 @@ def save_cache(cache: dict, path: str | os.PathLike | None = None) -> Path:
     return p
 
 
-def vmem_bytes(plan: dict[str, int], *, read_len: int, n: int,
-               alphabet: int = 4) -> int:
-    """Estimate the kernel's peak VMEM residency for a tile plan.
-
-    Mirrors the buffers ``kernels/fused_profile`` actually allocates:
-    pipelined input/output blocks, the rolled-IM/tie full blocks, the
-    2-slot prototype-slab double buffer (the automatic pipeline also
-    keeps two in flight, so the estimate is path-independent), and the
-    counts/accumulator scratch.
-    """
-    bb, bw, bs = plan["bb"], plan["bw"], plan["bs"]
-    w_pad = plan["w_pad"]
-    total = bb * read_len * 4             # token tile
-    total += bb * 4                       # lengths tile
-    total += n * alphabet * w_pad * 4     # rolled item memory (full block)
-    total += w_pad * 4                    # tie-break row
-    total += 2 * bs * w_pad * 4           # prototype slab, double-buffered
-    total += bb * 32 * bw * 4             # bit-counts scratch
-    total += bb * bs * 4                  # agreement accumulator scratch
-    total += bb * bs * 4                  # output tile
-    return total
-
-
-def candidate_plans(b: int, s: int, w: int) -> list[dict[str, int]]:
+def candidate_plans(b: int, s: int, w: int, *, read_len: int, n: int,
+                    alphabet: int = 4) -> list[dict[str, int]]:
     """Normalized, deduplicated tile plans for the candidate sweep."""
     seen: set[tuple[int, int, int]] = set()
     plans = []
     for bb in CANDIDATE_BB:
         for bw in CANDIDATE_BW:
             for bs in CANDIDATE_BS:
-                plan = ops.fused_tile_plan(b, s, w, bb=bb, bw=bw, bs=bs)
+                plan = ops.fused_tile_plan(b, s, w, read_len=read_len, n=n,
+                                           alphabet=alphabet, bb=bb, bw=bw,
+                                           bs=bs)
                 key = (plan["bb"], plan["bw"], plan["bs"])
                 if key not in seen:
                     seen.add(key)
@@ -166,7 +141,7 @@ def _time_plan(plan: dict[str, int], args, space: HDSpace,
 
 def tune(space: HDSpace, *, batch: int, num_prototypes: int, read_len: int,
          path: str | os.PathLike | None = None, force: bool = False,
-         trials: int = 2, budget: int = VMEM_BUDGET,
+         trials: int = 2, budget: int = ops.VMEM_BUDGET,
          seed: int = 0) -> tuple[dict[str, int], bool]:
     """Pick (and cache) the fastest feasible tiles for the live shape.
 
@@ -180,12 +155,12 @@ def tune(space: HDSpace, *, batch: int, num_prototypes: int, read_len: int,
     if entry is not None and not force:
         return {k: int(entry["tiles"][k]) for k in ("bb", "bw", "bs")}, True
 
-    plans = candidate_plans(batch, num_prototypes, space.num_words)
     cost = dict(read_len=read_len, n=space.ngram,
                 alphabet=space.alphabet_size)
-    feasible = [p for p in plans if vmem_bytes(p, **cost) <= budget]
+    plans = candidate_plans(batch, num_prototypes, space.num_words, **cost)
+    feasible = [p for p in plans if ops.vmem_bytes(p, **cost) <= budget]
     if not feasible:  # degenerate budget: keep the leanest candidate
-        feasible = [min(plans, key=lambda p: vmem_bytes(p, **cost))]
+        feasible = [min(plans, key=lambda p: ops.vmem_bytes(p, **cost))]
 
     args = _synthetic_inputs(space, batch, num_prototypes, read_len, seed)
     timed = [(_time_plan(p, args, space, trials), p) for p in feasible]
@@ -195,7 +170,7 @@ def tune(space: HDSpace, *, batch: int, num_prototypes: int, read_len: int,
         "tiles": tiles,
         "time_s": best_t,
         "swept": len(feasible),
-        "vmem_bytes": vmem_bytes(best, **cost),
+        "vmem_bytes": ops.vmem_bytes(best, **cost),
     }
     save_cache(cache, path)
     return tiles, False

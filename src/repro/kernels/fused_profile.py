@@ -27,44 +27,45 @@ inside the memristor array):
   copy.  Prototype HBM bytes per call drop from
   ``(B/bb) * S * W * 4`` to ``S * W * 4`` — amortized ``B/bb``-fold.
 * **Double-buffered prototype DMA** (``double_buffer=True``; the default
-  on real TPU).  The prototype array stays in HBM
+  whenever the kernel runs natively).  The prototype array stays in HBM
   (``memory_space=ANY``) and the kernel copies slab ``k+1`` into the
   spare half of a two-slot VMEM scratch *at the first cell of chunk
   ``k``*, overlapping the fetch with the whole slab's worth of
   XOR+popcount work.  The automatic pipeline only prefetches one grid
   step ahead — it would start fetching slab ``k+1`` during the *last*
-  cell of chunk ``k``, too late to hide a multi-megabyte copy.  Under
-  interpret mode and on non-TPU backends the kernel falls back to the
-  automatic pipeline (same math, same bytes; both paths are bit-exact
-  and parity-tested in ``tests/test_fused.py``).
+  cell of chunk ``k``, too late to hide a multi-megabyte copy.  Interpret
+  mode takes the automatic pipeline unless asked (same math, same bytes;
+  both paths are bit-exact and parity-tested in ``tests/test_fused.py``,
+  and both compile for v5e in ``tests/test_tpu_compile.py``).
 
 Per grid cell ``(k, i, j)``:
 
-  1. **Encode** the ``(bb, bw)`` word tile exactly as
-     ``hdc_encoder._kernel`` does: gather-free IM lookup (4 predicated
-     selects), per-bit bundling counters in ``(bb, 32, bw)`` scratch,
-     majority threshold with the tie-break vector, re-pack to
-     ``(bb, bw)`` uint32 — all VMEM.
+  1. **Encode** the ``(bb, bw)`` word tile with the encoder kernel's own
+     :func:`~repro.kernels.hdc_encoder.encode_tile`: lane-rotated token
+     windows, gather-free IM lookup (4 predicated selects), per-bit
+     bundling counters in ``(32, bb, bw)`` scratch, majority threshold
+     with the tie-break vector, re-pack to ``(bb, bw)`` uint32 — all
+     VMEM.
   2. **Search**: XOR the fresh tile against word tile ``j`` of prototype
-     slab ``k`` and accumulate popcounts into the persistent
-     ``(bb, bs)`` Hamming scratch.
+     slab ``k``, 128 prototype rows at a time, and accumulate popcounts
+     into the persistent ``(bb, bs)`` Hamming scratch.
   3. On the last word tile, flush ``agreement = dim - hamming`` into the
      ``(i, k)`` output block — the only HBM write of the whole query
      path besides the final scores.
 
 The word axis is innermost ("arbitrary": it carries the accumulator);
-the IM, tie, and prototype arrays arrive word-split as ``(..., W/bw,
-bw)`` so the per-cell word tile is a *sublane-dim* dynamic index (TPU
-supports those; lane-dim dynamic slices would need 128-alignment).
-Bit-exact with ``reference`` encode + agreement by construction — the
-encode math is byte-for-byte the encoder kernel's, and
+the IM, tie, and prototype arrays arrive word-split with the word tile
+on a *leading* dim (``(W/bw, ..., bw)``), so the per-cell word tile is a
+leading-dim dynamic index (lane-dim dynamic slices would need
+128-alignment).  Bit-exact with ``reference`` encode + agreement by
+construction — the encode is the encoder kernel's own code, and
 ``dim - popcount(xor)`` is the same exact integer identity both AM
 kernels use.
 
-VMEM per cell: ``bs*W*4`` (prototype slab; x2 when double-buffered) +
-``bb*bs*4`` (accumulator) + ``bb*bs*4`` (output block) + ``bb*32*bw*4``
-(counters) + ``n*alphabet*W*4`` (IM); callers bound ``bs`` per chunk
-(see ``ops.fused_agreement`` / ``repro.kernels.autotune``).
+VMEM per cell is dominated by the two ``bs*W*4`` prototype slabs;
+:func:`repro.kernels.ops.vmem_bytes` counts every buffer, and
+:func:`repro.kernels.ops.fused_tile_plan` bounds ``bs`` so the total
+fits ``ops.VMEM_BUDGET`` (a v5e core's default scoped VMEM is 16 MiB).
 """
 
 from __future__ import annotations
@@ -74,91 +75,63 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import (ANY, CompilerParams, VMEM,
-                                         SemaphoreDMA, interpret_default,
-                                         make_async_copy)
-
-WORD_BITS = 32
-
-
-def _unpack(words: jax.Array) -> jax.Array:
-    """(bb, bw) uint32 -> (bb, 32, bw) int32 bits (bit b in sublane b)."""
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, :, None]
-    return ((words[:, None, :] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
+from repro.kernels.hdc_encoder import (LANES, WORD_BITS, encode_tile,
+                                       pad_tokens, rolled_rows)
+from repro.kernels.interpret import interpret_default
 
 
-def _pack(bits: jax.Array) -> jax.Array:
-    """(bb, 32, bw) {0,1} -> (bb, bw) uint32."""
-    weights = (jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32))
-    return (bits.astype(jnp.uint32) * weights[None, :, None]).sum(
-        axis=1, dtype=jnp.uint32)
-
-
-def _encode_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j, *,
-                 n: int, alphabet: int, g: int) -> jax.Array:
+def _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j, *,
+            n: int, alphabet: int, g: int) -> jax.Array:
     """Encode word tile ``j`` of the batch tile: ``(bb, bw)`` uint32.
 
-    Same math as ``hdc_encoder._kernel``; ``im_ref``/``tie_ref`` are the
-    word-split ``(n, alphabet, W/bw, bw)`` / ``(1, W/bw, bw)`` views and
-    ``j`` picks the tile with a sublane-dim dynamic index.
+    ``im_ref``/``tie_ref`` are the word-split ``(W/bw, n*alphabet, bw)``
+    / ``(W/bw, 1, bw)`` views; ``j`` picks the tile on the leading dim.
     """
-    toks = tokens_ref[...]                       # (bb, L) int32
     m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
-    counts_ref[...] = jnp.zeros_like(counts_ref)
-    bb = counts_ref.shape[0]
-    bw = counts_ref.shape[-1]
-    im_tile = im_ref[:, :, j, :]                 # (n, alphabet, bw)
-
-    if g > 0:
-        def body(i, _):
-            window = jax.lax.dynamic_slice(toks, (0, i), (bb, n))  # (bb, n)
-            gram = jnp.zeros((bb, bw), jnp.uint32)
-            for jj in range(n):                   # bind: XOR of rho^j(B[c])
-                tok_j = window[:, jj][:, None]    # (bb, 1)
-                for a in range(alphabet):         # gather-free IM lookup
-                    row = im_tile[jj, a, :][None, :]
-                    gram = jnp.bitwise_xor(
-                        gram, jnp.where(tok_j == a, row, jnp.uint32(0)))
-            valid = (i < m[:, 0])[:, None, None]  # (bb, 1, 1)
-            counts_ref[...] += jnp.where(valid, _unpack(gram), 0)
-            return 0
-
-        jax.lax.fori_loop(0, g, body, 0)
-
-    counts = counts_ref[...]                      # (bb, 32, bw)
-    twice = 2 * counts
-    m_b = m[:, 0][:, None, None]
-    tie_bits = _unpack(tie_ref[:, j, :])[0:1]     # (1, 32, bw)
-    bits = jnp.where(twice == m_b, tie_bits,
-                     (twice > m_b).astype(jnp.int32))
-    return _pack(bits)                            # (bb, bw) — VMEM only
+    return encode_tile(tokens_ref, m, im_ref[j], tie_ref[j], counts_ref,
+                       n=n, alphabet=alphabet, g=g)
 
 
-def _search_tile(acc_ref, o_ref, q, p_tile, *, dim: int):
-    """Fold one encoded tile into the Hamming accumulator; flush on last j."""
-    x = jnp.bitwise_xor(q[:, None, :], p_tile[None, :, :])
-    acc_ref[...] += jnp.bitwise_count(x).astype(jnp.int32).sum(axis=-1)
+def _search_tile(acc_ref, o_ref, q, p_ref, j, *, dim: int):
+    """Fold one encoded tile into the Hamming accumulator; flush on last j.
 
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    ``p_ref`` is the ``(W/bw, bs, bw)`` prototype slab.  Its rows are
+    scored 128 at a time, which bounds the ``(bb, 128, bw)`` XOR
+    temporary whatever ``bs`` is.
+    """
+    bs = p_ref.shape[1]
+    sub = LANES if bs % LANES == 0 else bs
+
+    def rows(r, carry):
+        off = pl.multiple_of(r * sub, sub)
+        x = jnp.bitwise_xor(q[:, None, :], p_ref[j, pl.ds(off, sub), :][None])
+        acc_ref[:, pl.ds(off, sub)] += (
+            jnp.bitwise_count(x).astype(jnp.int32).sum(axis=-1))
+        return carry
+
+    jax.lax.fori_loop(0, bs // sub, rows, 0)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
         o_ref[...] = dim - acc_ref[...]
 
 
 def _kernel(tokens_ref, len_ref, im_ref, tie_ref, p_ref, o_ref,
             counts_ref, acc_ref, *, n: int, alphabet: int, g: int, dim: int):
-    """Automatic-pipeline variant: the ``(bs, W)`` prototype slab is a
-    BlockSpec block indexed by the chunk id only, so the pipeline fetches
-    it once per chunk and double-buffers the fetch across chunks."""
+    """Automatic-pipeline variant: the ``(W/bw, bs, bw)`` prototype slab
+    is a BlockSpec block indexed by the chunk id only, so the pipeline
+    fetches it once per chunk and double-buffers the fetch across chunks."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _encode_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
-                     n=n, alphabet=alphabet, g=g)
-    _search_tile(acc_ref, o_ref, q, p_ref[:, j, :], dim=dim)
+    q = _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
+                n=n, alphabet=alphabet, g=g)
+    _search_tile(acc_ref, o_ref, q, p_ref, j, dim=dim)
 
 
 def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
@@ -168,11 +141,10 @@ def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
     ``k+1``'s async copy is issued at the FIRST cell of chunk ``k`` —
     the whole slab's compute window hides the next fetch."""
     k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    bs = p_buf.shape[1]
 
     def slab_dma(slot, chunk):
-        return make_async_copy(p_hbm.at[pl.ds(chunk * bs, bs)],
-                               p_buf.at[slot], sem.at[slot])
+        return pltpu.make_async_copy(p_hbm.at[chunk], p_buf.at[slot],
+                                     sem.at[slot])
 
     @pl.when((i == 0) & (j == 0))
     def _rotate():
@@ -190,9 +162,9 @@ def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _encode_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
-                     n=n, alphabet=alphabet, g=g)
-    _search_tile(acc_ref, o_ref, q, p_buf[k % 2][:, j, :], dim=dim)
+    q = _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
+                n=n, alphabet=alphabet, g=g)
+    _search_tile(acc_ref, o_ref, q, p_buf.at[k % 2], j, dim=dim)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet", "dim", "bb",
@@ -220,8 +192,8 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         (``ops.fused_agreement`` pads once for the whole call).
       double_buffer: manually double-buffer the prototype-slab DMA
         (prototypes stay in HBM, two-slot VMEM scratch).  ``None`` picks
-        it on real TPU and falls back to the automatic pipeline under
-        interpret / non-TPU backends.  Both variants are bit-exact.
+        it whenever the kernel runs natively; interpret mode takes the
+        automatic pipeline unless asked.  Both variants are bit-exact.
 
     Returns:
       ``(B, S)`` int32 agreement counts in [0, dim] — bit-identical to
@@ -239,35 +211,37 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         f"pad upstream")
     interpret = interpret_default(interpret)
     if double_buffer is None:
-        double_buffer = (not interpret and make_async_copy is not None
-                         and jax.default_backend() == "tpu")
-    grid = (s // bs, b // bb, w // bw)
-    wt = w // bw
+        double_buffer = not interpret
+    wt, nk = w // bw, s // bs
+    grid = (nk, b // bb, wt)
 
-    # Word-split views: the per-cell word tile becomes a sublane-dim
+    # Word-split views: the per-cell word tile becomes a leading-dim
     # dynamic index instead of a lane-dim slice, and the IM / tie /
     # prototype block indices stop depending on j — the IM and tie are
     # fetched once per call, the prototype slab once per chunk.
-    im4 = im_rolled.reshape(n, alphabet, wt, bw)
-    tie3 = tie.reshape(1, wt, bw)
-    p3 = p_packed.reshape(s, wt, bw)
+    toks = pad_tokens(tokens)
+    im3 = rolled_rows(im_rolled).reshape(n * alphabet, wt, bw).transpose(
+        1, 0, 2)
+    tie3 = tie.reshape(wt, 1, bw)
+    p4 = p_packed.reshape(nk, bs, wt, bw).transpose(0, 2, 1, 3)
 
     common_specs = [
-        pl.BlockSpec((bb, length), lambda k, i, j: (i, 0)),
+        pl.BlockSpec((bb, toks.shape[1]), lambda k, i, j: (i, 0)),
         pl.BlockSpec((bb, 1), lambda k, i, j: (i, 0)),
-        pl.BlockSpec((n, alphabet, wt, bw), lambda k, i, j: (0, 0, 0, 0)),
-        pl.BlockSpec((1, wt, bw), lambda k, i, j: (0, 0, 0)),
+        pl.BlockSpec((wt, n * alphabet, bw), lambda k, i, j: (0, 0, 0)),
+        pl.BlockSpec((wt, 1, bw), lambda k, i, j: (0, 0, 0)),
     ]
-    scratch = [VMEM((bb, WORD_BITS, bw), jnp.int32),
-               VMEM((bb, bs), jnp.int32)]
+    scratch = [pltpu.VMEM((WORD_BITS, bb, bw), jnp.int32),
+               pltpu.VMEM((bb, bs), jnp.int32)]
     if double_buffer:
         kernel = _kernel_dma
-        p_spec = pl.BlockSpec(memory_space=ANY)
-        scratch = scratch + [VMEM((2, bs, wt, bw), jnp.uint32),
-                             SemaphoreDMA((2,))]
+        p_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = scratch + [pltpu.VMEM((2, wt, bs, bw), jnp.uint32),
+                             pltpu.SemaphoreType.DMA((2,))]
     else:
         kernel = _kernel
-        p_spec = pl.BlockSpec((bs, wt, bw), lambda k, i, j: (k, 0, 0))
+        p_spec = pl.BlockSpec((None, wt, bs, bw),
+                              lambda k, i, j: (k, 0, 0, 0))
 
     return pl.pallas_call(
         functools.partial(kernel, n=n, alphabet=alphabet, g=g, dim=dim),
@@ -276,7 +250,7 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         out_specs=pl.BlockSpec((bb, bs), lambda k, i, j: (i, k)),
         out_shape=jax.ShapeDtypeStruct((b, s), jnp.int32),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tokens, lengths, im4, tie3, p3)
+    )(toks, lengths, im3, tie3, p4)

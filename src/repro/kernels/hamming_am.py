@@ -17,8 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, VMEM, interpret_default
+from repro.kernels.interpret import interpret_default
 
 
 def _kernel(q_ref, p_ref, o_ref, acc_ref, *, dim: int):
@@ -70,8 +71,8 @@ def hamming_am(q_packed: jax.Array, p_packed: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, s), jnp.int32),
-        scratch_shapes=[VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_default(interpret),
     )(q_packed, p_packed)

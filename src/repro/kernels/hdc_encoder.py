@@ -11,14 +11,20 @@ TPU port of Acc-Demeter's encoder unit (paper §5.3).  Design notes:
   of the HD space is fully independent -> embarrassingly parallel grid
   over (batch, word-block), zero cross-block traffic.  This is the TPU
   realization of the "free shift" flip-flop chain.
-* **Counters layout** ``(bb, 32, bw)``: the lane dimension stays the
-  word-block (multiple of 128); the 32 bit positions of each word sit in
-  sublanes.
+* **Token windows by lane rotation.** A read's tokens lie along lanes.
+  The gram loop walks them in 128-position chunks: each chunk loads a
+  128-aligned ``(bb, 256)`` slice (an aligned dynamic lane offset) and
+  every gram rotates it so tokens ``i .. i+n-1`` sit in lanes
+  ``0 .. n-1``.  An unaligned dynamic lane slice does not lower on TPU.
+* **Counters layout** ``(32, bb, bw)``: one ``(bb, bw)`` tile per bit
+  position, so unpacking, counting and the majority are plain 2-D
+  per-tile ops.
 * Bundling majority (with tie-break vector) and re-packing happen in the
   same kernel — one HBM write of W words per read, nothing else leaves.
 
 Grid: (B/bb, W/bw); the whole gram loop for a read runs inside one grid
-cell, mirroring the paper's streaming encoder.
+cell, mirroring the paper's streaming encoder.  :func:`encode_tile` is
+shared with the fused encode->search kernel (``fused_profile``).
 """
 
 from __future__ import annotations
@@ -28,57 +34,111 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams, VMEM, interpret_default
+from repro.kernels.interpret import interpret_default
 
 WORD_BITS = 32
+LANES = 128
 
 
-def _unpack(words: jax.Array) -> jax.Array:
-    """(bb, bw) uint32 -> (bb, 32, bw) int32 bits (bit b in sublane b)."""
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, :, None]
-    return ((words[:, None, :] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
+def padded_length(length: int) -> int:
+    """Token row width the kernels read for ``length``-token sequences.
+
+    Rounded up to the lane tile plus one spare tile, so the 256-lane
+    chunk read for the last gram chunk stays in bounds.
+    """
+    return -(-length // LANES) * LANES + LANES
 
 
-def _pack(bits: jax.Array) -> jax.Array:
-    """(bb, 32, bw) {0,1} -> (bb, bw) uint32."""
-    weights = (jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32))
-    return (bits.astype(jnp.uint32) * weights[None, :, None]).sum(
-        axis=1, dtype=jnp.uint32)
+def pad_tokens(tokens: jax.Array) -> jax.Array:
+    """Zero-pad ``(B, L)`` tokens to ``(B, padded_length(L))``.
+
+    The pad is inert: a gram that reads it starts at or after the last
+    valid gram and is masked out of the counters.
+    """
+    length = tokens.shape[1]
+    return jnp.pad(tokens, ((0, 0), (0, padded_length(length) - length)))
+
+
+def rolled_rows(im_rolled: jax.Array) -> jax.Array:
+    """``(N, alphabet, W)`` rolled IM -> ``(N * alphabet, W)`` row table."""
+    n, alphabet, w = im_rolled.shape
+    return im_rolled.reshape(n * alphabet, w)
+
+
+def encode_tile(tokens_ref, m, im_rows, tie_row, counts_ref, *, n: int,
+                alphabet: int, g: int) -> jax.Array:
+    """Encode one ``(bb, bw)`` word tile of a batch tile, in VMEM.
+
+    Args:
+      tokens_ref: ``(bb, padded_length(L))`` int32 token ref.
+      m: ``(bb, 1)`` int32 count of valid grams per row.
+      im_rows: ``(n * alphabet, bw)`` uint32 rolled-IM rows of this tile.
+      tie_row: ``(1, bw)`` uint32 tie-break words of this tile.
+      counts_ref: ``(32, bb, bw)`` int32 scratch for the bit counters.
+      g: grams in an unpadded row (``L - n + 1``, at least 0).
+
+    Returns:
+      ``(bb, bw)`` uint32 packed majority-bundled HD words.
+    """
+    _, bb, bw = counts_ref.shape
+    if n > LANES:
+        raise ValueError(f"n-gram size {n} exceeds one {LANES}-lane tile")
+    counts_ref[...] = jnp.zeros_like(counts_ref)
+
+    def add_gram(win, pos):
+        # win: (bb, 2*LANES); lanes 0..n-1 hold tokens pos..pos+n-1.
+        gram = jnp.zeros((bb, bw), jnp.uint32)
+        for jj in range(n):                      # bind: XOR of rho^j(B[c])
+            tok = win[:, jj:jj + 1]              # (bb, 1)
+            for a in range(alphabet):            # gather-free IM lookup
+                k = jj * alphabet + a
+                gram = jnp.bitwise_xor(
+                    gram, jnp.where(tok == a, im_rows[k:k + 1, :],
+                                    jnp.uint32(0)))
+        # Rows past their last valid gram add nothing to the counters.
+        gram = jnp.where(pos < m, gram, jnp.uint32(0))
+        for b in range(WORD_BITS):
+            counts_ref[b] += ((gram >> b) & jnp.uint32(1)).astype(jnp.int32)
+
+    def chunk(c, count):
+        base = pl.multiple_of(c * LANES, LANES)
+        toks = tokens_ref[:, pl.ds(base, 2 * LANES)]   # (bb, 256)
+
+        def body(r, carry):
+            win = pltpu.roll(toks, (2 * LANES - r) % (2 * LANES), 1)
+            add_gram(win, base + r)
+            return carry
+
+        jax.lax.fori_loop(0, count, body, 0)
+
+    full, rem = divmod(g, LANES)
+    if full:
+        def full_chunk(c, carry):
+            chunk(c, LANES)
+            return carry
+
+        jax.lax.fori_loop(0, full, full_chunk, 0)
+    if rem:
+        chunk(jnp.int32(full), rem)
+
+    # Bundle: majority with tie-break (paper's thresholded counters).
+    packed = jnp.zeros((bb, bw), jnp.uint32)
+    for b in range(WORD_BITS):
+        twice = 2 * counts_ref[b]
+        tie_bit = (tie_row >> b) & jnp.uint32(1)
+        bit = jnp.where(twice == m, tie_bit,
+                        (twice > m).astype(jnp.uint32))
+        packed = packed | (bit << b)
+    return packed
 
 
 def _kernel(tokens_ref, len_ref, im_ref, tie_ref, o_ref, counts_ref,
             *, n: int, alphabet: int, g: int):
-    toks = tokens_ref[...]                       # (bb, L) int32
     m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
-    counts_ref[...] = jnp.zeros_like(counts_ref)
-    bw = o_ref.shape[-1]
-    bb = o_ref.shape[0]
-
-    if g > 0:
-        def body(i, _):
-            window = jax.lax.dynamic_slice(toks, (0, i), (bb, n))  # (bb, n)
-            gram = jnp.zeros((bb, bw), jnp.uint32)
-            for j in range(n):                    # bind: XOR of rho^j(B[c])
-                tok_j = window[:, j][:, None]     # (bb, 1)
-                for a in range(alphabet):         # gather-free IM lookup
-                    row = im_ref[j, a, :][None, :]
-                    gram = jnp.bitwise_xor(
-                        gram, jnp.where(tok_j == a, row, jnp.uint32(0)))
-            valid = (i < m[:, 0])[:, None, None]  # (bb, 1, 1)
-            counts_ref[...] += jnp.where(valid, _unpack(gram), 0)
-            return 0
-
-        jax.lax.fori_loop(0, g, body, 0)
-
-    # Bundle: majority with tie-break (paper's thresholded counters).
-    counts = counts_ref[...]                      # (bb, 32, bw)
-    twice = 2 * counts
-    m_b = m[:, 0][:, None, None]
-    tie_bits = _unpack(tie_ref[...])[0:1]         # (1, 32, bw)
-    bits = jnp.where(twice == m_b, tie_bits,
-                     (twice > m_b).astype(jnp.int32))
-    o_ref[...] = _pack(bits)
+    o_ref[...] = encode_tile(tokens_ref, m, im_ref[...], tie_ref[...],
+                             counts_ref, n=n, alphabet=alphabet, g=g)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet", "bb", "bw",
@@ -104,21 +164,22 @@ def hdc_encode(tokens: jax.Array, lengths: jax.Array, im_rolled: jax.Array,
     bb, bw = min(bb, b), min(bw, w)
     assert b % bb == 0 and w % bw == 0, (
         f"(B={b}, W={w}) must tile by (bb={bb}, bw={bw}); pad upstream")
+    toks = pad_tokens(tokens)
     grid = (b // bb, w // bw)
 
     return pl.pallas_call(
         functools.partial(_kernel, n=n, alphabet=alphabet, g=g),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb, length), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, toks.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((n, alphabet, bw), lambda i, j: (0, 0, j)),
+            pl.BlockSpec((n * alphabet, bw), lambda i, j: (0, j)),
             pl.BlockSpec((1, bw), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bb, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, w), jnp.uint32),
-        scratch_shapes=[VMEM((bb, WORD_BITS, bw), jnp.int32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((WORD_BITS, bb, bw), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret_default(interpret),
-    )(tokens, lengths, im_rolled, tie)
+    )(toks, lengths, rolled_rows(im_rolled), tie)

@@ -86,17 +86,49 @@ def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def fused_tile_plan(b: int, s: int, w: int, *, bb: int = 8, bw: int = 128,
+#: VMEM bytes a fused tile plan may hold.  A v5e core has 16 MiB of
+#: scoped VMEM by default; the margin leaves room for the compiler's own
+#: staging and temporaries.
+VMEM_BUDGET = 12 * 2 ** 20
+
+
+def vmem_bytes(plan: dict[str, int], *, read_len: int, n: int,
+               alphabet: int = 4) -> int:
+    """Estimate the fused kernel's peak VMEM residency for a tile plan.
+
+    Mirrors the buffers ``kernels/fused_profile`` allocates: pipelined
+    input/output blocks (two of each), the word-split IM/tie full
+    blocks, the 2-slot prototype slab (the automatic pipeline also keeps
+    two in flight, so the estimate is path-independent), the counter and
+    accumulator scratch, and the XOR temporaries of one 128-row block.
+    Linear in ``bs``, which :func:`fused_tile_plan` uses to bound it.
+    """
+    bb, bw, bs = plan["bb"], plan["bw"], plan["bs"]
+    w_pad = plan["w_pad"]
+    total = 2 * bb * _hdc_encoder.padded_length(read_len) * 4  # tokens
+    total += 2 * bb * 128 * 4             # lengths tile (lane-padded)
+    total += 2 * n * alphabet * w_pad * 4  # rolled item memory
+    total += 2 * 8 * w_pad * 4            # tie-break rows (sublane-padded)
+    total += 2 * bs * w_pad * 4           # prototype slab, two slots
+    total += 32 * bb * bw * 4             # bit-counter scratch
+    total += bb * bs * 4                  # agreement accumulator scratch
+    total += 2 * bb * bs * 4              # output tile
+    total += 3 * bb * 128 * bw * 4        # XOR / popcount temporaries
+    return total
+
+
+def fused_tile_plan(b: int, s: int, w: int, *, read_len: int, n: int,
+                    alphabet: int = 4, bb: int = 8, bw: int = 128,
                     bs: int = 4096) -> dict[str, int]:
     """The padded shapes + grid :func:`fused_agreement` will actually run.
 
     One place owns the clamp/pad arithmetic so the kernel launch and the
     analytic traffic accounting (``benchmarks/smoke.py`` /
     ``benchmarks/memory.py`` / ``repro.kernels.autotune``) can never
-    drift apart.  The prototype chunking pads S ONCE to
-    ``n_chunks * bs`` (``bs`` re-balanced so the pad is < one chunk) —
-    not per chunk, so the accumulator waste and the timing no longer
-    vary with ``S % bs``.
+    drift apart.  ``bs`` is first bounded so the plan's
+    :func:`vmem_bytes` fits :data:`VMEM_BUDGET` (at D=40960 the two
+    prototype slabs alone take 10 KiB per row), then re-balanced so S
+    pads ONCE to ``n_chunks * bs`` with less than one chunk of waste.
 
     Returns a dict with the effective ``bb``/``bw``/``bs``, the padded
     ``b_pad``/``w_pad``/``s_pad``, ``n_chunks``, and
@@ -108,17 +140,21 @@ def fused_tile_plan(b: int, s: int, w: int, *, bb: int = 8, bw: int = 128,
     b_pad = _ceil_to(b, max(bb, 8))
     bw = min(bw, w)
     w_pad = _ceil_to(w, bw)
+    shape = {"bb": bb, "bw": bw, "w_pad": w_pad}
+    cost = dict(read_len=read_len, n=n, alphabet=alphabet)
+    fixed = vmem_bytes({**shape, "bs": 0}, **cost)
+    per_row = vmem_bytes({**shape, "bs": 1}, **cost) - fixed
+    bs_fit = (VMEM_BUDGET - fixed) // per_row // 128 * 128
     # Re-balance the requested chunk rows over ceil(S/bs) chunks, rounded
     # to the 128-row output lane tile, then pad S to the chunk grid: the
     # total pad is < one chunk (vs up to 127 rows per chunk before).
-    bs = max(128, min(bs, _ceil_to(s, 128)))
+    bs = max(128, min(bs, bs_fit, _ceil_to(s, 128)))
     n_chunks = -(-s // bs)
     bs = _ceil_to(-(-s // n_chunks), 128)
     n_chunks = -(-s // bs)
     s_pad = n_chunks * bs
-    return {"bb": bb, "bw": bw, "bs": bs, "b_pad": b_pad, "w_pad": w_pad,
-            "s_pad": s_pad, "n_chunks": n_chunks,
-            "proto_bytes_per_call": s_pad * w_pad * 4}
+    return {**shape, "bs": bs, "b_pad": b_pad, "s_pad": s_pad,
+            "n_chunks": n_chunks, "proto_bytes_per_call": s_pad * w_pad * 4}
 
 
 @functools.partial(jax.jit, static_argnames=("space", "bb", "bw", "bs",
@@ -148,13 +184,16 @@ def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
         ``(bb, bs)`` accumulator resident in VMEM.  Re-balanced and
         padded once via :func:`fused_tile_plan`.
       double_buffer: forwarded to the kernel (``None`` = auto: manual
-        DMA double-buffering on real TPU, automatic pipeline elsewhere).
+        DMA double-buffering on TPU, automatic pipeline in interpret
+        mode).
 
     Returns:
       ``(B, S)`` int32 agreement in [0, space.dim].
     """
     b, s = tokens.shape[0], prototypes.shape[0]
-    plan = fused_tile_plan(b, s, space.num_words, bb=bb, bw=bw, bs=bs)
+    plan = fused_tile_plan(b, s, space.num_words, read_len=tokens.shape[1],
+                           n=space.ngram, alphabet=space.alphabet_size,
+                           bb=bb, bw=bw, bs=bs)
     im_rolled = item_memory.rolled(im, space.ngram)
     toks = _pad_to(tokens.astype(jnp.int32), 0, max(plan["bb"], 8))
     lens = _pad_to(lengths.astype(jnp.int32)[:, None], 0, max(plan["bb"], 8))

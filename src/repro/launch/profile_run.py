@@ -21,6 +21,7 @@ import time
 from repro.core import HDSpace
 from repro.eval import score_profile
 from repro.genomics import fasta, synth
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import (ArraySource, FastqSource, ProfilerConfig,
                             ProfilingSession, ReadSource, available_backends,
                             resolve_backend)
@@ -154,6 +155,7 @@ def main() -> None:
     ap.add_argument("--noise-aware-iters", type=int, default=2,
                     help="retraining passes for --noise-aware-refdb")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.list_backends:
         from repro.pipeline.backend import options_schema

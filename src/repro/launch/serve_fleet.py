@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.core import HDSpace
 from repro.genomics import synth
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import (ArraySource, ProfilerConfig, ProfilingSession,
                             available_backends)
 from repro.serve import FleetController, RefDBRegistry
@@ -223,6 +224,7 @@ def main() -> None:
                          " auto-picked host kill, one fleet swap,"
                          " --check on")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         config = ProfilerConfig(

@@ -48,6 +48,7 @@ import numpy as np
 from repro import obs
 from repro.core import HDSpace
 from repro.genomics import synth
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import (ArraySource, ProfilerConfig, ProfilingSession,
                             available_backends)
 from repro.serve import ProfilingService, RefDBRegistry, TenantRouter
@@ -400,6 +401,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI-sized run (implies --check)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # Observability is opt-in: the globals flip before any session /
     # service / router is constructed, so every layer resolves them.

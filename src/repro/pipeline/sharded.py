@@ -43,6 +43,7 @@ the capacity win that lets the AM outgrow one device's memory.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -51,11 +52,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import assoc_memory
 from repro.core.assoc_memory import RefDB
 from repro.distributed import sharding
-from repro.distributed.sharding import shard_map_compat as _shard_map
 from repro.core.bitops import pad_to_multiple
 from repro.pipeline.backend import register_backend, resolve_backend
 from repro.pipeline.config import ProfilerConfig
 from repro.pipeline.options import Option, OptionsSchema, non_negative
+
+#: Pallas kernels have no replication rule, so the shard bodies run with
+#: shard_map's replication (VMA) check off.
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 #: Options consumed by this backend; everything else is forwarded to the
 #: base backend's config (e.g. pcm_sim device knobs under base=pcm_sim).

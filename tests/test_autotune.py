@@ -9,7 +9,7 @@ import pytest
 
 from repro.core.hd_space import HDSpace
 from repro.genomics import synth
-from repro.kernels import autotune
+from repro.kernels import autotune, ops
 from repro.pipeline import ProfilerConfig, ProfilingSession, SyntheticSource
 
 SP = HDSpace(dim=256, ngram=4, z_threshold=3.0)
@@ -82,13 +82,13 @@ def test_distinct_shapes_get_distinct_keys():
 # -- feasibility filter -----------------------------------------------------
 
 def test_vmem_filter_drops_oversized_plans(tmp_path):
-    plans = autotune.candidate_plans(64, 5000, 512)
     cost = dict(read_len=1024, n=8)
+    plans = autotune.candidate_plans(64, 5000, 512, **cost)
     budget = 2 ** 20
-    feasible = [p for p in plans if autotune.vmem_bytes(p, **cost) <= budget]
-    dropped = [p for p in plans if autotune.vmem_bytes(p, **cost) > budget]
+    feasible = [p for p in plans if ops.vmem_bytes(p, **cost) <= budget]
+    dropped = [p for p in plans if ops.vmem_bytes(p, **cost) > budget]
     assert dropped, "sweep must contain plans a 1 MiB budget rejects"
-    assert all(autotune.vmem_bytes(p, **cost) <= budget for p in feasible)
+    assert all(ops.vmem_bytes(p, **cost) <= budget for p in feasible)
 
 
 def test_degenerate_budget_still_tunes(tmp_path):

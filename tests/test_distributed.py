@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.checkpoint import checkpointer as ck
 from repro.configs import get_config
@@ -21,9 +22,8 @@ from repro.train import train_step as ts
 
 
 def _amesh(shape, names):
-    # AbstractMesh's constructor drifted across jax releases; the compat
-    # helper handles both spellings (device-free, so no mesh leaks).
-    return sharding.abstract_mesh(shape, names)
+    # Device-free mesh: resolves sharding rules without any real devices.
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 # -- sharding rules (AbstractMesh: no devices needed) ----------------------------

@@ -97,12 +97,12 @@ def test_fused_double_buffer_path_matches_reference():
 def test_fused_tile_plan_pads_once():
     """Regression for the old per-chunk 128-row pad: an odd S is padded
     once to the chunk grid, wasting less than one chunk in total."""
-    plan = ops.fused_tile_plan(16, 387, 16, bs=129)
+    plan = ops.fused_tile_plan(16, 387, 16, read_len=40, n=5, bs=129)
     assert plan["bs"] % 128 == 0
     assert plan["s_pad"] == plan["n_chunks"] * plan["bs"]
     assert plan["s_pad"] - 387 < plan["bs"]
     # tiny bs requests are clamped, not allowed to explode the pad
-    plan = ops.fused_tile_plan(16, 300, 16, bs=8)
+    plan = ops.fused_tile_plan(16, 300, 16, read_len=40, n=5, bs=8)
     assert plan["bs"] >= 128 and plan["s_pad"] - 300 < plan["bs"]
 
 

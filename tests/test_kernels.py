@@ -28,6 +28,21 @@ def test_am_agreement_sweep(b, s, w):
     np.testing.assert_array_equal(got_p, want)
 
 
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, platform, interpret):
+    """Native on TPU, interpreted on CPU, refused anywhere else — never
+    a silent interpreter run on an accelerator that failed to start."""
+    from repro.kernels import interpret as mode
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: platform)
+    assert mode.interpret_default(True) is True
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="natively on TPU"):
+            mode.interpret_default(None)
+    else:
+        assert mode.interpret_default(None) is interpret
+
+
 @pytest.mark.parametrize("bm,bn,bk", [(8, 8, 128), (4, 16, 256)])
 def test_am_matmul_blockings(bm, bn, bk):
     q, p = _rand_packed(8, 16), _rand_packed(16, 16)

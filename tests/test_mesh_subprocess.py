@@ -12,12 +12,16 @@ import sys
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
+# ``jax.make_mesh`` builds Explicit axes by default; the GSPMD-style code
+# under test (logical-axis rules + sharding propagation) targets Auto ones.
+_PRELUDE = "from jax.sharding import AxisType\nAUTO = AxisType.Auto\n"
+
 
 def _run(snippet: str, devices: int = 8, timeout: int = 900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
-    out = subprocess.run([sys.executable, "-c", snippet], env=env,
+    out = subprocess.run([sys.executable, "-c", _PRELUDE + snippet], env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, f"stderr:\n{out.stderr[-3000:]}"
     return out.stdout
@@ -37,7 +41,7 @@ batch = {'tokens': jnp.asarray(rng.integers(0, cfg.vocab, (8,16)), jnp.int32),
 state = ts.init_train_state(jax.random.key(0), cfg, tc)
 step = ts.make_train_step(cfg, tc)
 _, m1 = jax.jit(step)(jax.tree.map(lambda x: x, state), batch)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AUTO,) * 2)
 rules = sharding.TRAIN_RULES
 with sharding.use_rules(mesh, rules):
     st_sh = param_specs.state_shardings(state, mesh, rules)
@@ -63,7 +67,7 @@ rng = np.random.default_rng(0)
 tok = jnp.asarray(rng.integers(0, cfg.vocab, (4,)), jnp.int32)
 caches = lm.init_cache(cfg, 4, 32, dtype=jnp.float32)
 logits1, _ = lm.decode_step(params, tok, caches, jnp.int32(0), cfg)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'), axis_types=(AUTO,) * 2)
 rules = sharding.DECODE_RULES
 with sharding.use_rules(mesh, rules):
     p_sh = param_specs.param_shardings(params, mesh, rules)
@@ -101,9 +105,8 @@ est = comp.init_state({'w': jnp.zeros((16,))})
 def f(gl):
     out, _ = comp.compressed_psum({'w': gl[0]}, est, 'data')
     return out['w']
-from repro.distributed.sharding import shard_map_compat
-got = jax.jit(shard_map_compat(f, mesh=mesh2, in_specs=P('data'),
-                               out_specs=P()))(g)
+got = jax.jit(jax.shard_map(f, mesh=mesh2, in_specs=P('data'),
+                            out_specs=P(), check_vma=False))(g)
 np.testing.assert_allclose(np.asarray(got), np.asarray(g.mean(0)), atol=0.02)
 print('pipeline + compressed psum OK')
 """)
@@ -125,7 +128,7 @@ toks = jnp.asarray(rng.integers(0, 4, (32, 64)), jnp.int32)
 lens = jnp.full((32,), 64, jnp.int32)
 q = dm.encode_reads(toks, lens)
 res1 = dm.classify_queries(q, db)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(AUTO,) * 2)
 qs = jax.device_put(q, NamedSharding(mesh, P('data', 'model')))
 res2 = dm.classify_queries(qs, db)
 np.testing.assert_array_equal(np.asarray(res1.scores), np.asarray(res2.scores))
