@@ -78,7 +78,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.hdc_encoder import (LANES, WORD_BITS, encode_tile,
-                                       pad_tokens, rolled_rows)
+                                       pad_tokens)
 from repro.kernels.interpret import interpret_default
 
 
@@ -220,8 +220,7 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
     # prototype block indices stop depending on j — the IM and tie are
     # fetched once per call, the prototype slab once per chunk.
     toks = pad_tokens(tokens)
-    im3 = rolled_rows(im_rolled).reshape(n * alphabet, wt, bw).transpose(
-        1, 0, 2)
+    im3 = im_rolled.reshape(n * alphabet, wt, bw).transpose(1, 0, 2)
     tie3 = tie.reshape(wt, 1, bw)
     p4 = p_packed.reshape(nk, bs, wt, bw).transpose(0, 2, 1, 3)
 

@@ -61,12 +61,6 @@ def pad_tokens(tokens: jax.Array) -> jax.Array:
     return jnp.pad(tokens, ((0, 0), (0, padded_length(length) - length)))
 
 
-def rolled_rows(im_rolled: jax.Array) -> jax.Array:
-    """``(N, alphabet, W)`` rolled IM -> ``(N * alphabet, W)`` row table."""
-    n, alphabet, w = im_rolled.shape
-    return im_rolled.reshape(n * alphabet, w)
-
-
 def encode_tile(tokens_ref, m, im_rows, tie_row, counts_ref, *, n: int,
                 alphabet: int, g: int) -> jax.Array:
     """Encode one ``(bb, bw)`` word tile of a batch tile, in VMEM.
@@ -182,4 +176,4 @@ def hdc_encode(tokens: jax.Array, lengths: jax.Array, im_rolled: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret_default(interpret),
-    )(toks, lengths, rolled_rows(im_rolled), tie)
+    )(toks, lengths, im_rolled.reshape(n * alphabet, w), tie)
