@@ -27,12 +27,19 @@ default at construction time".  Nothing here ever enters a jax trace,
 so enabling observability cannot perturb bit-exactness — and
 :func:`jax_trace` is the separate, explicitly opt-in
 ``jax.profiler`` capture for kernel-level timelines.
+
+:func:`span` opens a host span on the profiler's own clock around each
+phase of the serving step; with no profiler running it records nothing,
+so the spans need no switch.  :func:`compile_count` counts the backend
+compiles of the process, which the service step reports as a span
+argument.
 """
 
 from __future__ import annotations
 
 import contextlib
 import pathlib
+import threading
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, HistogramState,
                                MetricsRegistry, NullRegistry, RATIO_BUCKETS,
@@ -119,6 +126,55 @@ def jax_trace(log_dir: str | pathlib.Path | None):
         yield
 
 
+def span(name: str, **args):
+    """A host span named ``name`` on the device trace's clock.
+
+    A context manager over ``jax.profiler.TraceAnnotation``: under a
+    running ``jax.profiler`` capture (:func:`jax_trace`, or any
+    ``jax.profiler.start_trace``) the span lands on the host plane of the
+    same trace as the device's ops, with ``args`` (ints, floats, strings)
+    as its event stats; with no capture running it records nothing and
+    costs about a microsecond.  ``set_metadata(**more)`` on the returned
+    object adds arguments before the span closes.
+    """
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **args)
+
+
+#: The ``jax.monitoring`` duration event of one backend compile (or
+#: persistent-cache load) of a jitted program.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_lock = threading.Lock()
+_compiles: int | None = None      # None until the listener is registered
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    global _compiles
+    if event == BACKEND_COMPILE_EVENT:
+        with _compile_lock:
+            _compiles += 1
+
+
+def compile_count() -> int:
+    """Backend compiles in this process since the first call.
+
+    The first call registers one process-wide ``jax.monitoring``
+    listener; the difference of two calls is the number of compiles in
+    between, on any thread.
+    """
+    global _compiles
+    with _compile_lock:
+        if _compiles is None:
+            import jax
+
+            _compiles = 0
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+        return _compiles
+
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "HistogramState", "MetricsRegistry",
     "NullRegistry", "RATIO_BUCKETS", "TIME_BUCKETS_S",
@@ -127,5 +183,6 @@ __all__ = [
     "TraceRecorder", "assemble_trace",
     "NULL_METRICS", "NULL_TRACER",
     "enable_metrics", "enable_tracing", "disable", "metrics", "tracer",
-    "resolve_metrics", "resolve_tracer", "jax_trace",
+    "resolve_metrics", "resolve_tracer", "jax_trace", "span",
+    "BACKEND_COMPILE_EVENT", "compile_count",
 ]

@@ -16,8 +16,7 @@ Design constraints, in priority order:
   inert no-op object behind the same interface, and hot paths guard any
   real work (``time.perf_counter()``, label merging) behind the
   registry's ``enabled`` flag — so disabled observability costs one
-  attribute load per site, which the benchmark smoke's overhead guard
-  (:mod:`benchmarks.smoke`) pins at < 2%.
+  attribute load per site.
 * **Never perturb results.**  All recording is host-side Python; nothing
   here touches a jax trace, so metrics-on and metrics-off runs are
   bit-identical (``tests/test_obs.py`` enforces this per backend).
@@ -30,10 +29,10 @@ Instruments are label-keyed like Prometheus: one instrument name owns
 many series, one per distinct label set::
 
     reg = MetricsRegistry()
-    lat = reg.histogram("serve_batch_seconds", "cohort latency",
+    lat = reg.histogram("serve_step_phase_seconds", "step phase time",
                         unit="s")
-    lat.observe(0.012, backend="pallas_fused")
-    lat.percentile(99, backend="pallas_fused")
+    lat.observe(0.012, phase="wait")
+    lat.percentile(99, phase="wait")
 
 Histograms use *fixed* bucket upper bounds (cumulative-free storage,
 constant memory per series) with quantiles estimated by linear

@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import pathlib
-import time
 from typing import Callable
 
 import jax
@@ -79,17 +78,9 @@ class ProfilingSession:
         self.backend: Backend = (backend if backend is not None
                                  else resolve_backend(config.backend, config))
         self._obs = obs.resolve_metrics(metrics)
-        self._m_batch_time = self._obs.histogram(
-            "session_classify_batch_seconds",
-            "classify_batch dispatch wall time per dispatch path "
-            "(async backends: time to hand off, not to complete)",
-            unit="s")
         self._m_batches = self._obs.counter(
             "session_classify_batches_total",
             "classify_batch calls per backend and dispatch path")
-        self._m_transfers = self._obs.counter(
-            "session_host_transfers_total",
-            "device->host array transfers on the query path")
         self.refdb: RefDB | None = None
         self.refdb_loaded_from_cache = False
         self.refdb_cache_file: pathlib.Path | None = None
@@ -282,35 +273,32 @@ class ProfilingSession:
           index: stream position recorded on the :class:`BatchResult`.
         """
         db = self._require_refdb(refdb)
-        toks, lens = jnp.asarray(tokens), jnp.asarray(lengths)
         fused_full = getattr(self.backend, "tokens_species_scores", None)
         fused = getattr(self.backend, "tokens_agreement", None)
-        recording = self._obs.enabled
-        t0 = time.perf_counter() if recording else 0.0
-        if fused_full is not None:
-            path = "tokens_species_scores"
-            scores = fused_full(toks, lens, db.prototypes,
-                                db.proto_species, db.num_species)
-            res = self._from_scores(
-                scores, threshold_bits=self.space.threshold_bits)
-            q = None
-        elif fused is not None:
-            path = "tokens_agreement"
-            agree = fused(toks, lens, db.prototypes)
-            res = self._from_agreement(
-                agree, db.proto_species, num_species=db.num_species,
-                threshold_bits=self.space.threshold_bits)
-            q = None
-        else:
-            path = "encode_classify"
-            q = self.encode_reads(toks, lens)
-            res = self.classify_queries(q, db)
-        if recording:
-            # Host-side timing only — the jax computation is untouched,
-            # so recording can never move a bit of the result.
-            labels = {"backend": self.config.backend, "path": path}
-            self._m_batch_time.observe(time.perf_counter() - t0, **labels)
-            self._m_batches.inc(1, **labels)
+        path = ("tokens_species_scores" if fused_full is not None
+                else "tokens_agreement" if fused is not None
+                else "encode_classify")
+        # The span ends once the work is handed to the device: copies in,
+        # backend call and tail launched, no result waited for.
+        with obs.span("session.dispatch", path=path):
+            toks, lens = jnp.asarray(tokens), jnp.asarray(lengths)
+            if fused_full is not None:
+                scores = fused_full(toks, lens, db.prototypes,
+                                    db.proto_species, db.num_species)
+                res = self._from_scores(
+                    scores, threshold_bits=self.space.threshold_bits)
+                q = None
+            elif fused is not None:
+                agree = fused(toks, lens, db.prototypes)
+                res = self._from_agreement(
+                    agree, db.proto_species, num_species=db.num_species,
+                    threshold_bits=self.space.threshold_bits)
+                q = None
+            else:
+                q = self.encode_reads(toks, lens)
+                res = self.classify_queries(q, db)
+        if self._obs.enabled:
+            self._m_batches.inc(1, backend=self.config.backend, path=path)
         n = len(toks) if num_valid is None else num_valid
         return BatchResult(index=index, queries=q, classification=res,
                            num_valid=n)
@@ -340,21 +328,9 @@ class ProfilingSession:
             n = res.num_valid
             acc.add(np.asarray(res.classification.hits)[:n],
                     np.asarray(res.classification.category)[:n])
-            self.note_host_transfers(2)       # hits + category to host
             if on_batch is not None:
                 on_batch(res)
         return acc.finalize(np.asarray(db.genome_lengths), db.species_names)
-
-    def note_host_transfers(self, n: int) -> None:
-        """Count ``n`` device->host transfers against this session.
-
-        Called wherever classification outputs cross to numpy — here in
-        :meth:`profile` and by the serving demux
-        (:meth:`repro.serve.profiler_service.ProfilingService.step`) —
-        so the snapshot shows how chatty each dispatch path is.
-        """
-        if self._obs.enabled:
-            self._m_transfers.inc(n, backend=self.config.backend)
 
     # ----------------------------------------------------------------------
     def _place(self, db: RefDB) -> RefDB:

@@ -249,9 +249,11 @@ class ProfilingService:
             "serve_admission_wait_seconds",
             "Queue wait from submit until the request went RUNNING.",
             unit="s")
-        self._m_batch_time = self._obs.histogram(
-            "serve_batch_seconds",
-            "Wall time of one cohort classify_batch, demux included.",
+        self._m_phase = self._obs.histogram(
+            "serve_step_phase_seconds",
+            "Host wall time of each phase of a step that ran a cohort: "
+            "admit, assemble, dispatch, wait (device and copy to host), "
+            "demux.",
             unit="s")
         self._m_fill_ratio = self._obs.histogram(
             "serve_cohort_fill_ratio",
@@ -260,6 +262,10 @@ class ProfilingService:
         self._m_padding_rows = self._obs.counter(
             "serve_cohort_padding_rows_total",
             "Wasted (padding) rows across executed cohorts.")
+        self._m_padding_tokens = self._obs.counter(
+            "serve_cohort_padding_tokens_total",
+            "Padded token positions (rows x bucket less the live reads' "
+            "lengths) across executed cohorts.")
         self._m_reads = self._obs.counter(
             "serve_reads_classified_total",
             "Reads classified and demuxed into request accumulators.")
@@ -320,55 +326,88 @@ class ProfilingService:
         """Run one cohort (admit -> classify -> demux); False when idle.
 
         This is the whole serving hot loop at its smallest granularity;
-        ``run_until_idle`` and the background worker just call it.
+        ``run_until_idle`` and the background worker just call it.  A step
+        with work runs inside the ``serve.step`` span, whose children tile
+        it in order: ``serve.admit`` (around ``serve.pull``),
+        ``serve.assemble``, the session's ``session.dispatch``,
+        ``serve.wait`` and ``serve.demux`` (``docs/OBSERVABILITY.md``).
         """
-        with self._lock:
-            self._activate_locked()
-            active = list(self._active)
-            want = self._sched.slots - len(self._sched)
-        # Source iteration (file IO) happens outside the lock — only the
-        # pumping thread touches the iterators, so submissions and
-        # snapshots stay responsive while a slow FASTQ parses.
-        events = self._pull_reads(active, want)
-        with self._lock:
-            self._apply_admission_locked(events)
-            self._finish_exhausted_locked()
-            cohort = self._sched.next_cohort()
+        if self.idle:
+            return False
+        with obs.span("serve.step") as step_span:
+            compiles = obs.compile_count()
+            t_admit = time.perf_counter()
+            with obs.span("serve.admit"):
+                cohort = self._admit()
             if cohort is None:
                 return False
-        # Classify outside the lock too: the service stays responsive
-        # while the backend crunches the batch.
-        tokens, lengths, live = self._assemble(cohort)
-        recording = self._obs.enabled
-        t_exec = time.perf_counter() if recording or self._tracer.enabled \
-            else 0.0
-        res = self.session.classify_batch(tokens, lengths,
-                                          num_valid=len(live))
-        hits = np.asarray(res.classification.hits)
-        cat = np.asarray(res.classification.category)
-        t_demux = time.perf_counter() if recording or self._tracer.enabled \
-            else 0.0
-        with self._work:
-            if recording:
-                slots = self._sched.slots
-                self._m_batch_time.observe(
-                    t_demux - t_exec, backend=self.session.config.backend,
-                    **self._labels)
-                self._m_fill_ratio.observe(len(live) / slots, **self._labels)
-                self._m_padding_rows.inc(slots - len(live), **self._labels)
-                self._m_reads.inc(len(live), **self._labels)
-            # hits + category: two device->host pulls per cohort (the
-            # session guards on its own registry's enabled flag).
-            self.session.note_host_transfers(2)
-            if recording or self._tracer.enabled:
-                for h in {r.handle for r in live}:
-                    h.timeline.mark("first_execute", at=t_exec)
+            t_assemble = time.perf_counter()
+            with obs.span("serve.assemble"):
+                tokens, lengths, live = self._assemble(cohort)
+                real = int(lengths.sum())
+            # Classify outside the lock too: the service stays responsive
+            # while the backend crunches the batch.
+            t_dispatch = time.perf_counter()
+            res = self.session.classify_batch(tokens, lengths,
+                                              num_valid=len(live))
+            t_wait = time.perf_counter()
+            with obs.span("serve.wait"):
+                hits = np.asarray(res.classification.hits)
+                cat = np.asarray(res.classification.category)
+            t_demux = time.perf_counter()
+            with obs.span("serve.demux"), self._work:
+                handles = self._demux_locked(live, hits, cat)
+                for h in handles:
+                    h.timeline.mark("first_execute", at=t_dispatch)
                     h.timeline.mark("accumulate", at=t_demux)
-            self._demux_locked(live, hits, cat)
-            self.cohorts_run += 1
-            self._finish_exhausted_locked()
-            self._work.notify_all()
+                index = self.cohorts_run
+                self.cohorts_run += 1
+                self._finish_exhausted_locked()
+                self._work.notify_all()
+            t_end = time.perf_counter()
+            slots = self._sched.slots
+            step_span.set_metadata(
+                cohort=index, rows=len(live), slots=slots,
+                bucket=cohort.length, tokens=real,
+                requests=" ".join(h.request_id for h in handles),
+                compiles=obs.compile_count() - compiles)
+        if self._obs.enabled:
+            for phase, took in (("admit", t_assemble - t_admit),
+                                ("assemble", t_dispatch - t_assemble),
+                                ("dispatch", t_wait - t_dispatch),
+                                ("wait", t_demux - t_wait),
+                                ("demux", t_end - t_demux)):
+                self._m_phase.observe(took, phase=phase, **self._labels)
+            self._m_fill_ratio.observe(len(live) / slots, **self._labels)
+            self._m_padding_rows.inc(slots - len(live), **self._labels)
+            self._m_padding_tokens.inc(slots * cohort.length - real,
+                                       **self._labels)
+            self._m_reads.inc(len(live), **self._labels)
         return True
+
+    def _admit(self) -> Cohort[_Read] | None:
+        """Activate queued requests, pull their reads, form a cohort.
+
+        Pulling again when a pull only ended or failed streams lets the
+        requests that freed their slots be replaced at once, so a step
+        returns without a cohort only when no live stream has a read.
+        """
+        while True:
+            with self._lock:
+                self._activate_locked()
+                active = list(self._active)
+                want = self._sched.slots - len(self._sched)
+            # Source iteration (file IO) happens outside the lock — only
+            # the pumping thread touches the iterators, so submissions and
+            # snapshots stay responsive while a slow FASTQ parses.
+            with obs.span("serve.pull"):
+                events = self._pull_reads(active, want)
+            with self._lock:
+                self._apply_admission_locked(events)
+                self._finish_exhausted_locked()
+                cohort = self._sched.next_cohort()
+            if cohort is not None or not events:
+                return cohort
 
     @property
     def idle(self) -> bool:
@@ -554,8 +593,9 @@ class ProfilingService:
         return tokens, lengths, live
 
     def _demux_locked(self, live: list[_Read], hits: np.ndarray,
-                      cat: np.ndarray) -> None:
-        """Split cohort rows back into per-request accumulators, in order."""
+                      cat: np.ndarray) -> list[ProfileHandle]:
+        """Split cohort rows back into per-request accumulators, in order;
+        returns the requests that received rows."""
         per: dict[ProfileHandle, list[int]] = {}
         for i, r in enumerate(live):
             if r.handle.state is RequestState.RUNNING:
@@ -564,6 +604,7 @@ class ProfilingService:
             h._acc.add(hits[idx], cat[idx])
             h.reads_classified += len(idx)
             self.reads_classified += len(idx)
+        return list(per)
 
     def _finish_exhausted_locked(self) -> None:
         # classified == admitted implies nothing of this request's is

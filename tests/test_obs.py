@@ -263,7 +263,8 @@ def test_metrics_do_not_perturb_results(sample, refdb, backend):
     assert on.profile(src).to_json() == off.profile(src).to_json()
     # the enabled twin really recorded (the comparison wasn't vacuous)
     assert reg.counter("session_classify_batches_total").total() > 0
-    assert reg.histogram("session_classify_batch_seconds").merged().count > 0
+    assert reg.counter("session_classify_batches_total").total() \
+        == math.ceil(len(sample.lengths) / cfg.batch_size)
 
 
 def test_pcm_sim_metrics_bit_exact_with_device_noise(sample, refdb):
@@ -306,7 +307,9 @@ def test_service_metrics_and_traces_end_to_end(sample, refdb):
     assert reg.counter("serve_requests_total").value(state="done") == 4
     assert reg.counter("serve_reads_classified_total").total() == reads
     assert reg.histogram("serve_admission_wait_seconds").merged().count == 4
-    assert reg.histogram("serve_batch_seconds").merged().count > 0
+    for phase in ("admit", "assemble", "dispatch", "wait", "demux"):
+        assert reg.histogram("serve_step_phase_seconds").count(
+            phase=phase) == service.cohorts_run > 0
     fill = reg.histogram("serve_cohort_fill_ratio",
                          buckets=obs.RATIO_BUCKETS).merged()
     assert fill.count > 0 and fill.sum <= fill.count    # ratios in (0, 1]
@@ -354,6 +357,162 @@ def test_cancelled_and_failed_requests_still_trace(sample, refdb):
         assert [s.name for s in trace.spans] == ["request", "admission"]
     assert reg.counter("serve_requests_total").value(state="cancelled") == 1
     assert reg.counter("serve_requests_total").value(state="failed") == 1
+
+
+# -- host spans on the device trace's clock ------------------------------------
+
+PHASES = ("serve.admit", "serve.assemble", "session.dispatch", "serve.wait",
+          "serve.demux")
+
+
+def _host_spans(log_dir):
+    """``(line, name, start, end, stats)`` of every program span."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    (f,) = pathlib.Path(log_dir).rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(f)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for k, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("serve.", "session.")):
+                    out.append((k, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_service(tmp_path_factory, sample, refdb):
+    """Four requests served under ``jax.profiler``, from cold caches."""
+    import jax
+
+    session = ProfilingSession(_config(backend="reference"))
+    session.adopt_refdb(refdb)
+    given = []
+    classify = session.classify_batch
+
+    def recorded(tokens, lengths, **kw):
+        given.append((np.asarray(tokens), np.asarray(lengths)))
+        return classify(tokens, lengths, **kw)
+
+    session.classify_batch = recorded
+    reg = obs.MetricsRegistry()
+    service = ProfilingService(session, max_active=2, metrics=reg)
+    handles = [service.submit(s) for s in _slices(sample, 4)]
+    log_dir = tmp_path_factory.mktemp("jaxprof")
+    jax.clear_caches()                  # the first cohort meets a new shape
+    with obs.jax_trace(log_dir):
+        service.run_until_idle()
+    reports = [h.result(timeout=0).to_json() for h in handles]
+    return {"spans": _host_spans(log_dir), "given": given, "reg": reg,
+            "handles": handles, "reports": reports,
+            "cohorts": service.cohorts_run}
+
+
+def _steps(spans):
+    """The ``serve.step`` spans of steps that ran a cohort."""
+    return sorted((s for s in spans
+                   if s[1] == "serve.step" and "cohort" in s[4]),
+                  key=lambda s: s[2])
+
+
+def _inside(spans, step, name):
+    return [s for s in spans if s[1] == name and s[0] == step[0]
+            and step[2] <= s[2] and s[3] <= step[3]]
+
+
+def test_step_spans_nest_and_tile_the_step(traced_service):
+    spans = traced_service["spans"]
+    steps = _steps(spans)
+    assert len(steps) == traced_service["cohorts"] == 6
+    # Requests end on cohort boundaries here: only the drain's last step,
+    # whose streams all ended without a read, ran no cohort, and its
+    # span carries no arguments.
+    bare = [s for s in spans if s[1] == "serve.step" and not s[4]]
+    assert len(bare) <= 1 and len(bare) + len(steps) == sum(
+        s[1] == "serve.step" for s in spans)
+    for step in steps:
+        children = [c for n in PHASES for c in _inside(spans, step, n)]
+        assert [c[1] for c in sorted(children, key=lambda c: c[2])] \
+            == list(PHASES)
+        assert sum(c[3] - c[2] for c in children) \
+            >= 0.9 * (step[3] - step[2])
+        (admit,) = _inside(spans, step, "serve.admit")
+        assert _inside(spans, admit, "serve.pull")     # one per pull
+        (dispatch,) = _inside(spans, step, "session.dispatch")
+        assert dispatch[4] == {"path": "encode_classify"}
+    # every program span belongs to a step
+    every = [s for s in spans if s[1] == "serve.step"]
+    assert all(any(s[0] == st[0] and st[2] <= s[2] and s[3] <= st[3]
+                   for st in every) for s in spans)
+
+
+def test_step_span_counts_equal_the_arrays_dispatched(traced_service):
+    steps = _steps(traced_service["spans"])
+    for k, (step, (tokens, lengths)) in enumerate(
+            zip(steps, traced_service["given"])):
+        args = step[4]
+        assert args["cohort"] == k
+        assert args["rows"] == int((lengths > 0).sum())
+        assert args["slots"] == lengths.shape[0] == tokens.shape[0]
+        assert args["bucket"] == tokens.shape[1]
+        assert args["tokens"] == int(lengths.sum())
+    ids = {h.request_id for h in traced_service["handles"]}
+    named = [set(st[4]["requests"].split()) for st in steps]
+    assert all(n and n <= ids for n in named)
+    assert set().union(*named) == ids
+    # the padding counter counts what the spans leave out of slots x bucket
+    padded = sum(st[4]["slots"] * st[4]["bucket"] - st[4]["tokens"]
+                 for st in steps)
+    assert traced_service["reg"].counter(
+        "serve_cohort_padding_tokens_total").total() == padded > 0
+
+
+def test_step_span_counts_compiles(traced_service):
+    compiles = [st[4]["compiles"] for st in _steps(traced_service["spans"])]
+    assert compiles[0] >= 1             # new shape: the session compiled
+    assert compiles[1:] == [0] * (len(compiles) - 1)    # warm steps
+
+
+def test_request_execute_phase_matches_its_cohorts(traced_service):
+    """first_execute / accumulate are stamped with no registry or recorder
+    enabled, inside the spans of the cohorts that carry the request id."""
+    steps = _steps(traced_service["spans"])
+    for h in traced_service["handles"]:
+        mine = [st for st in steps if h.request_id in st[4]["requests"]
+                .split()]
+        assert mine
+        execute = h.timeline.elapsed("first_execute", "accumulate")
+        assert execute is not None and execute > 0
+        assert execute <= (mine[-1][3] - mine[0][2]) / 1e9
+
+
+def test_profiler_leaves_results_bit_identical(traced_service, sample,
+                                               refdb):
+    session = ProfilingSession(_config(backend="reference"))
+    session.adopt_refdb(refdb)
+    service = ProfilingService(session, max_active=2)
+    handles = [service.submit(s) for s in _slices(sample, 4)]
+    service.run_until_idle()
+    assert [h.result(timeout=0).to_json() for h in handles] \
+        == traced_service["reports"]
+
+
+def test_spans_record_nothing_without_a_profiler(tmp_path):
+    import jax
+
+    with obs.span("serve.step", rows=3) as s:
+        s.set_metadata(tokens=5)
+    with obs.jax_trace(tmp_path):
+        with obs.span("serve.other", rows=4):
+            pass
+    assert [(s[1], s[4]) for s in _host_spans(tmp_path)] \
+        == [("serve.other", {"rows": 4})]
+    before = obs.compile_count()
+    jax.jit(lambda x: x * 3 - 1)(np.arange(7)).block_until_ready()
+    assert obs.compile_count() - before >= 1
 
 
 def test_router_and_registry_metrics_touchpoints(tmp_path, sample, extra):
