@@ -3,8 +3,10 @@
 The fused kernel's throughput is set by three tile knobs — ``bb`` (batch
 rows), ``bw`` (word lanes), ``bs`` (prototype rows per chunk) — whose
 best values depend on the platform (VMEM size, DMA latency) and the live
-problem shape.  This module sweeps candidate configs under a VMEM-budget
-feasibility filter, times :func:`repro.kernels.ops.fused_agreement` on
+problem shape.  This module sweeps candidate configs under a VMEM
+feasibility filter (tile buffers within the budget; with the kernel's
+encoded-batch cache, within the chip's VMEM), times
+:func:`repro.kernels.ops.fused_agreement` on
 deterministic synthetic inputs at the live shape, and persists the
 winner in an on-disk JSON cache so every later session/service/fleet
 process with the same (platform, device kind, B, W, S, dim) key reuses
@@ -158,9 +160,15 @@ def tune(space: HDSpace, *, batch: int, num_prototypes: int, read_len: int,
     cost = dict(read_len=read_len, n=space.ngram,
                 alphabet=space.alphabet_size)
     plans = candidate_plans(batch, num_prototypes, space.num_words, **cost)
-    feasible = [p for p in plans if ops.vmem_bytes(p, **cost) <= budget]
+
+    def held(p):  # VMEM a call holds: tile buffers + encoded-batch cache
+        return ops.vmem_bytes(p, **cost) + p["cache_bytes"]
+
+    ceiling = ops.vmem_ceiling()
+    feasible = [p for p in plans
+                if ops.vmem_bytes(p, **cost) <= budget and held(p) <= ceiling]
     if not feasible:  # degenerate budget: keep the leanest candidate
-        feasible = [min(plans, key=lambda p: ops.vmem_bytes(p, **cost))]
+        feasible = [min(plans, key=held)]
 
     args = _synthetic_inputs(space, batch, num_prototypes, read_len, seed)
     timed = [(_time_plan(p, args, space, trials), p) for p in feasible]
@@ -170,7 +178,7 @@ def tune(space: HDSpace, *, batch: int, num_prototypes: int, read_len: int,
         "tiles": tiles,
         "time_s": best_t,
         "swept": len(feasible),
-        "vmem_bytes": ops.vmem_bytes(best, **cost),
+        "vmem_bytes": held(best),
     }
     save_cache(cache, path)
     return tiles, False

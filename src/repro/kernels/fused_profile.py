@@ -38,17 +38,28 @@ inside the memristor array):
   both paths are bit-exact and parity-tested in ``tests/test_fused.py``,
   and both compile for v5e in ``tests/test_tpu_compile.py``).
 
+* **Encoded-batch cache** (calls of two chunks or more).  The grid
+  visits every ``(i, j)`` tile once per chunk, but a tile's encoding does
+  not depend on the chunk.  So the cells of chunk 0 store each encoded
+  ``(bb, bw)`` tile in a ``(B/bb, W/bw, bb, bw)`` VMEM scratch and every
+  later chunk reads it back: each read is encoded once per call, whatever
+  the chunk count (the encode is ~90% of a chunk's work at AFS20 widths).
+  The grid runs in order on the one TensorCore (all axes "arbitrary"),
+  so the scratch outlives the cells.  A one-chunk call has nothing to
+  reuse and allocates no cache.
+
 Per grid cell ``(k, i, j)``:
 
-  1. **Encode** the ``(bb, bw)`` word tile with the encoder kernel's own
+  1. **Encode** (chunk 0, or every cell of a one-chunk call) the
+     ``(bb, bw)`` word tile with the encoder kernel's own
      :func:`~repro.kernels.hdc_encoder.encode_tile`: lane-rotated token
      windows, gather-free IM lookup (4 predicated selects), per-bit
      bundling counters in ``(32, bb, bw)`` scratch, majority threshold
      with the tie-break vector, re-pack to ``(bb, bw)`` uint32 — all
-     VMEM.
-  2. **Search**: XOR the fresh tile against word tile ``j`` of prototype
-     slab ``k``, 128 prototype rows at a time, and accumulate popcounts
-     into the persistent ``(bb, bs)`` Hamming scratch.
+     VMEM.  Chunks after the first load the tile from the cache instead.
+  2. **Search**: XOR the tile against word tile ``j`` of prototype slab
+     ``k``, 128 prototype rows at a time, and accumulate popcounts into
+     the persistent ``(bb, bs)`` Hamming scratch.
   3. On the last word tile, flush ``agreement = dim - hamming`` into the
      ``(i, k)`` output block — the only HBM write of the whole query
      path besides the final scores.
@@ -62,10 +73,14 @@ construction — the encode is the encoder kernel's own code, and
 ``dim - popcount(xor)`` is the same exact integer identity both AM
 kernels use.
 
-VMEM per cell is dominated by the two ``bs*W*4`` prototype slabs;
+The tile buffers are dominated by the two ``bs*W*4`` prototype slabs;
 :func:`repro.kernels.ops.vmem_bytes` counts every buffer, and
-:func:`repro.kernels.ops.fused_tile_plan` bounds ``bs`` so the total
-fits ``ops.VMEM_BUDGET`` (a v5e core's default scoped VMEM is 16 MiB).
+:func:`repro.kernels.ops.fused_tile_plan` bounds ``bs`` so they fit
+``ops.VMEM_BUDGET`` (a v5e core's default scoped VMEM is 16 MiB).  The
+cache adds ``B*W*4`` bytes on top (20 MiB for 4096 reads at D=40960);
+the plan raises the call's scoped-VMEM limit to hold it
+(``vmem_limit_bytes``) and splits batches too large for the chip's VMEM
+into several calls.
 """
 
 from __future__ import annotations
@@ -82,16 +97,30 @@ from repro.kernels.hdc_encoder import (LANES, WORD_BITS, encode_tile,
 from repro.kernels.interpret import interpret_default
 
 
-def _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j, *,
-            n: int, alphabet: int, g: int) -> jax.Array:
-    """Encode word tile ``j`` of the batch tile: ``(bb, bw)`` uint32.
+def _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
+                k, i, j, *, n: int, alphabet: int, g: int) -> jax.Array:
+    """Encoded word tile ``j`` of batch tile ``i``: ``(bb, bw)`` uint32.
 
     ``im_ref``/``tie_ref`` are the word-split ``(W/bw, n*alphabet, bw)``
     / ``(W/bw, 1, bw)`` views; ``j`` picks the tile on the leading dim.
+    With no cache (one chunk) every cell encodes.  With one, the cells of
+    chunk ``k == 0`` encode and store the tile at ``[i, j]`` of the
+    ``(B/bb, W/bw, bb, bw)`` cache, and every cell reads it from there.
     """
-    m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
-    return encode_tile(tokens_ref, m, im_ref[j], tie_ref[j], counts_ref,
-                       n=n, alphabet=alphabet, g=g)
+    def encode():
+        m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
+        return encode_tile(tokens_ref, m, im_ref[j], tie_ref[j], counts_ref,
+                           n=n, alphabet=alphabet, g=g)
+
+    if not q_cache:
+        return encode()
+    (q_ref,) = q_cache
+
+    @pl.when(k == 0)
+    def _fill():
+        q_ref[i, j] = encode()
+
+    return q_ref[i, j]
 
 
 def _search_tile(acc_ref, o_ref, q, p_ref, j, *, dim: int):
@@ -119,23 +148,25 @@ def _search_tile(acc_ref, o_ref, q, p_ref, j, *, dim: int):
 
 
 def _kernel(tokens_ref, len_ref, im_ref, tie_ref, p_ref, o_ref,
-            counts_ref, acc_ref, *, n: int, alphabet: int, g: int, dim: int):
+            counts_ref, acc_ref, *q_cache, n: int, alphabet: int, g: int,
+            dim: int):
     """Automatic-pipeline variant: the ``(W/bw, bs, bw)`` prototype slab
     is a BlockSpec block indexed by the chunk id only, so the pipeline
-    fetches it once per chunk and double-buffers the fetch across chunks."""
-    j = pl.program_id(2)
+    fetches it once per chunk and double-buffers the fetch across chunks.
+    ``q_cache`` is the encoded-batch scratch, or empty for one chunk."""
+    k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
-                n=n, alphabet=alphabet, g=g)
+    q = _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
+                    k, i, j, n=n, alphabet=alphabet, g=g)
     _search_tile(acc_ref, o_ref, q, p_ref, j, dim=dim)
 
 
 def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
-                counts_ref, acc_ref, p_buf, sem, *,
+                counts_ref, acc_ref, p_buf, sem, *q_cache,
                 n: int, alphabet: int, g: int, dim: int):
     """Manual double-buffer variant: prototypes stay in HBM and slab
     ``k+1``'s async copy is issued at the FIRST cell of chunk ``k`` —
@@ -162,20 +193,22 @@ def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _encode(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, j,
-                n=n, alphabet=alphabet, g=g)
+    q = _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
+                    k, i, j, n=n, alphabet=alphabet, g=g)
     _search_tile(acc_ref, o_ref, q, p_buf.at[k % 2], j, dim=dim)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet", "dim", "bb",
                                              "bw", "bs", "interpret",
-                                             "double_buffer"))
+                                             "double_buffer",
+                                             "vmem_limit_bytes"))
 def fused_profile(tokens: jax.Array, lengths: jax.Array,
                   im_rolled: jax.Array, tie: jax.Array,
                   p_packed: jax.Array, *, n: int, dim: int,
                   alphabet: int = 4, bb: int = 8, bw: int = 128,
                   bs: int | None = None, interpret: bool | None = None,
-                  double_buffer: bool | None = None) -> jax.Array:
+                  double_buffer: bool | None = None,
+                  vmem_limit_bytes: int | None = None) -> jax.Array:
     """Agreement of every read against every prototype, single kernel.
 
     Args:
@@ -194,6 +227,10 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         (prototypes stay in HBM, two-slot VMEM scratch).  ``None`` picks
         it whenever the kernel runs natively; interpret mode takes the
         automatic pipeline unless asked.  Both variants are bit-exact.
+      vmem_limit_bytes: the call's scoped-VMEM limit; ``None`` keeps the
+        compiler's default (16 MiB on v5e).  A call of two chunks or more
+        holds a ``B*W*4``-byte encoded-batch cache besides its tile
+        buffers, and ``ops.fused_tile_plan`` sizes the limit for both.
 
     Returns:
       ``(B, S)`` int32 agreement counts in [0, dim] — bit-identical to
@@ -241,6 +278,8 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         kernel = _kernel
         p_spec = pl.BlockSpec((None, wt, bs, bw),
                               lambda k, i, j: (k, 0, 0, 0))
+    if nk > 1:  # encoded-batch cache: chunk 0 encodes, later chunks reuse
+        scratch = scratch + [pltpu.VMEM((b // bb, wt, bb, bw), jnp.uint32)]
 
     return pl.pallas_call(
         functools.partial(kernel, n=n, alphabet=alphabet, g=g, dim=dim),
@@ -250,6 +289,7 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, s), jnp.int32),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(toks, lengths, im3, tie3, p4)

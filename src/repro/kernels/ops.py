@@ -86,10 +86,36 @@ def _ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-#: VMEM bytes a fused tile plan may hold.  A v5e core has 16 MiB of
-#: scoped VMEM by default; the margin leaves room for the compiler's own
-#: staging and temporaries.
+#: The compiler's default scoped-VMEM limit of a v5e core.
+SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+
+#: VMEM bytes a fused tile plan's buffers may hold, under the default
+#: scoped limit.
 VMEM_BUDGET = 12 * 2 ** 20
+
+#: Room left above what a plan counts, for the compiler's own staging
+#: and temporaries; also kept below the chip's capacity.
+VMEM_HEADROOM = SCOPED_VMEM_DEFAULT - VMEM_BUDGET
+
+#: A v5e TensorCore's VMEM (128 MiB).  Off the chip — interpret mode,
+#: and compiles for a described v5e — plans assume it, so they split a
+#: batch exactly as the chip would.
+V5E_VMEM_CAPACITY = 128 * 2 ** 20
+
+
+def vmem_capacity() -> int:
+    """VMEM bytes of one TensorCore of the chip the kernels run on."""
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas import tpu as pltpu
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    return V5E_VMEM_CAPACITY
+
+
+def vmem_ceiling() -> int:
+    """Most VMEM a fused call may count: :func:`vmem_capacity` less
+    :data:`VMEM_HEADROOM` twice, once inside the raised scoped limit and
+    once between that limit and the capacity."""
+    return vmem_capacity() - 2 * VMEM_HEADROOM
 
 
 def vmem_bytes(plan: dict[str, int], *, read_len: int, n: int,
@@ -119,7 +145,7 @@ def vmem_bytes(plan: dict[str, int], *, read_len: int, n: int,
 
 def fused_tile_plan(b: int, s: int, w: int, *, read_len: int, n: int,
                     alphabet: int = 4, bb: int = 8, bw: int = 128,
-                    bs: int = 4096) -> dict[str, int]:
+                    bs: int = 4096) -> dict[str, int | None]:
     """The padded shapes + grid :func:`fused_agreement` will actually run.
 
     One place owns the clamp/pad arithmetic so the kernel launch and the
@@ -130,11 +156,23 @@ def fused_tile_plan(b: int, s: int, w: int, *, read_len: int, n: int,
     prototype slabs alone take 10 KiB per row), then re-balanced so S
     pads ONCE to ``n_chunks * bs`` with less than one chunk of waste.
 
+    A call of two chunks or more also holds the kernel's encoded-batch
+    cache, ``b_call * w_pad * 4`` bytes (``cache_bytes``; 0 with one
+    chunk).  Where the tile buffers plus the cache exceed
+    :data:`VMEM_BUDGET`, ``vmem_limit_bytes`` raises the call's scoped
+    VMEM to hold them with :data:`VMEM_HEADROOM` to spare, so the
+    buffers and cache stay within :func:`vmem_ceiling`.  A batch whose
+    cache cannot fit is split into ``n_calls`` kernel calls of ``b_call`` rows,
+    each streaming the prototypes once.
+
     Returns a dict with the effective ``bb``/``bw``/``bs``, the padded
-    ``b_pad``/``w_pad``/``s_pad``, ``n_chunks``, and
-    ``proto_bytes_per_call`` — the prototype-stream HBM bytes one fused
-    call moves (each ``(bs, W)`` slab is fetched once per chunk and
-    reused across every batch tile; see ``kernels/fused_profile``).
+    ``b_pad``/``w_pad``/``s_pad``, ``n_chunks``, ``b_call``/``n_calls``,
+    ``cache_bytes``, ``vmem_limit_bytes`` (``None``: the compiler's
+    default), ``encodes`` (encode passes per read: 1 with the cache, as
+    with one chunk) and ``proto_bytes_per_call`` — the prototype-stream
+    HBM bytes one fused call moves (each ``(bs, W)`` slab is fetched once
+    per chunk and reused across every batch tile; see
+    ``kernels/fused_profile``).
     """
     bb = min(bb, 8 * ((b + 7) // 8))
     b_pad = _ceil_to(b, max(bb, 8))
@@ -153,8 +191,27 @@ def fused_tile_plan(b: int, s: int, w: int, *, read_len: int, n: int,
     bs = _ceil_to(-(-s // n_chunks), 128)
     n_chunks = -(-s // bs)
     s_pad = n_chunks * bs
-    return {**shape, "bs": bs, "b_pad": b_pad, "s_pad": s_pad,
-            "n_chunks": n_chunks, "proto_bytes_per_call": s_pad * w_pad * 4}
+    plan = {**shape, "bs": bs, "b_pad": b_pad, "s_pad": s_pad,
+            "n_chunks": n_chunks, "b_call": b_pad, "n_calls": 1,
+            "cache_bytes": 0, "vmem_limit_bytes": None,
+            "encodes": 1, "proto_bytes_per_call": s_pad * w_pad * 4}
+    if n_chunks == 1:
+        return plan
+    # The encoded-batch cache: split the batch into calls whose cache
+    # fits under the chip's VMEM beside the tile buffers.
+    tiles = vmem_bytes(plan, **cost)
+    align = max(bb, 8)
+    fit = (vmem_ceiling() - tiles) // (w_pad * 4)
+    n_calls = -(-b_pad // max(align, fit // align * align))
+    b_call = _ceil_to(-(-b_pad // n_calls), align)
+    n_calls = -(-b_pad // b_call)
+    cache_bytes = b_call * w_pad * 4
+    limit = None
+    if tiles + cache_bytes > VMEM_BUDGET:
+        limit = _ceil_to(tiles + cache_bytes + VMEM_HEADROOM, 2 ** 20)
+    return {**plan, "b_pad": n_calls * b_call, "b_call": b_call,
+            "n_calls": n_calls, "cache_bytes": cache_bytes,
+            "vmem_limit_bytes": limit}
 
 
 @functools.partial(jax.jit, static_argnames=("space", "bb", "bw", "bs",
@@ -166,13 +223,19 @@ def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
     """Fused steps 3+4: read tokens -> agreement, no encoded HBM matrix.
 
     ONE :func:`repro.kernels.fused_profile.fused_profile` call covers the
-    whole ``(B, S)`` output: the ``bs`` prototype chunking is the
+    whole ``(B, S)`` output (a batch too large for the chip's VMEM takes
+    several, below): the ``bs`` prototype chunking is the
     kernel's outermost grid axis (no per-chunk retrace, no host concat),
     each ``(bs, W)`` prototype slab is fetched once per chunk and reused
     across every batch tile, and on TPU the next slab's DMA is manually
     double-buffered behind the current slab's compute.  The encoded
-    query tile lives only in VMEM, so the ``(B, W)`` packed matrix (and
-    the ±1 bf16 expansion of the matmul path) never touches HBM.
+    query tiles live only in VMEM, so the ``(B, W)`` packed matrix (and
+    the ±1 bf16 expansion of the matmul path) never touches HBM; with two
+    chunks or more the kernel keeps them there as a ``B*W*4``-byte cache,
+    so each read is encoded once per call, under the scoped-VMEM limit
+    the plan asks for (:func:`fused_tile_plan`'s ``vmem_limit_bytes``).
+    A batch too large for that cache on the chip is split into several
+    calls of ``b_call`` rows (:func:`fused_tile_plan`).
     Bit-identical to
     ``am_agreement(hdc_encode(tokens, lengths, im, tie, space), p, dim)``.
 
@@ -195,8 +258,8 @@ def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
                            n=space.ngram, alphabet=space.alphabet_size,
                            bb=bb, bw=bw, bs=bs)
     im_rolled = item_memory.rolled(im, space.ngram)
-    toks = _pad_to(tokens.astype(jnp.int32), 0, max(plan["bb"], 8))
-    lens = _pad_to(lengths.astype(jnp.int32)[:, None], 0, max(plan["bb"], 8))
+    toks = _pad_to(tokens.astype(jnp.int32), 0, plan["b_call"])
+    lens = _pad_to(lengths.astype(jnp.int32)[:, None], 0, plan["b_call"])
     # Pad the word axis to the tile and the prototype axis to the chunk
     # grid: zero IM/tie/prototype words encode (and score) as zeros, so
     # padding is inert to the exact agreement; pad rows are sliced off.
@@ -204,8 +267,12 @@ def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
     tie_row = _pad_to(tie[None, :], 1, plan["bw"])
     protos = _pad_to(_pad_to(jnp.asarray(prototypes), 1, plan["bw"]),
                      0, plan["bs"])
-    out = _fused_profile.fused_profile(
-        toks, lens, im_rolled, tie_row, protos, n=space.ngram,
-        dim=space.dim, alphabet=space.alphabet_size, bb=plan["bb"],
-        bw=plan["bw"], bs=plan["bs"], double_buffer=double_buffer)
+    rows = plan["b_call"]
+    out = jnp.concatenate([_fused_profile.fused_profile(
+        toks[r:r + rows], lens[r:r + rows], im_rolled, tie_row, protos,
+        n=space.ngram, dim=space.dim, alphabet=space.alphabet_size,
+        bb=plan["bb"], bw=plan["bw"], bs=plan["bs"],
+        double_buffer=double_buffer,
+        vmem_limit_bytes=plan["vmem_limit_bytes"])
+        for r in range(0, plan["b_pad"], rows)])
     return out[:b, :s]

@@ -138,6 +138,8 @@ class PallasFusedBackend(_BackendBase):
             self._autotune = False
         #: (S, L) shape the cached tuning was resolved for
         self._tuned_for: tuple[int, int] | None = None
+        #: kernel_plan's answers, per (B, L, S, tiles)
+        self._plans: dict[tuple, dict[str, int]] = {}
 
     # -- Backend protocol (standalone kernels; RefDB build + sharded) ------
     def encode(self, tokens: jax.Array, lengths: jax.Array) -> jax.Array:
@@ -170,6 +172,27 @@ class PallasFusedBackend(_BackendBase):
             self.tiles = {**self.tiles, **tiles}
             self._tuned_for = (num_prototypes, read_len)
         return self.tiles
+
+    def kernel_plan(self, batch: int, read_len: int, num_prototypes: int
+                    ) -> dict[str, int]:
+        """``chunks`` and ``encodes`` of the kernel call for this shape.
+
+        The ``session.dispatch`` span's arguments: the tile plan's
+        prototype chunks, and encode passes per read (1 with the
+        kernel's encoded-batch cache).  Worked out once per shape.
+        """
+        t = self._resolve_tiles(num_prototypes, read_len)
+        key = (batch, read_len, num_prototypes, t["bb"], t["bw"], t["bs"])
+        if key not in self._plans:
+            from repro.kernels import ops
+            plan = ops.fused_tile_plan(
+                batch, num_prototypes, self.space.num_words,
+                read_len=read_len, n=self.space.ngram,
+                alphabet=self.space.alphabet_size, bb=t["bb"],
+                bw=min(t["bw"], self.space.num_words), bs=t["bs"])
+            self._plans[key] = {"chunks": plan["n_chunks"],
+                                "encodes": plan["encodes"]}
+        return self._plans[key]
 
     # -- fused capability (ProfilingSession.classify_batch dispatch) -------
     def tokens_agreement(self, tokens: jax.Array, lengths: jax.Array,

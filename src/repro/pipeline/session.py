@@ -278,9 +278,12 @@ class ProfilingSession:
         path = ("tokens_species_scores" if fused_full is not None
                 else "tokens_agreement" if fused is not None
                 else "encode_classify")
+        kernel_plan = getattr(self.backend, "kernel_plan", None)
+        plan = {} if kernel_plan is None else kernel_plan(
+            *np.shape(tokens), db.prototypes.shape[0])
         # The span ends once the work is handed to the device: copies in,
         # backend call and tail launched, no result waited for.
-        with obs.span("session.dispatch", path=path):
+        with obs.span("session.dispatch", path=path, **plan):
             toks, lens = jnp.asarray(tokens), jnp.asarray(lengths)
             if fused_full is not None:
                 scores = fused_full(toks, lens, db.prototypes,

@@ -164,6 +164,7 @@ class ShardedBackend:
         if getattr(self.base, "tokens_agreement", None) is not None:
             self.tokens_agreement = self._tokens_agreement
             self.tokens_species_scores = self._tokens_species_scores
+            self.kernel_plan = self._kernel_plan
             self._tok_agree = jax.jit(self._tokens_agreement_impl)
             self._tok_scores = jax.jit(self._tokens_scores_impl,
                                        static_argnames=("num_species",))
@@ -219,6 +220,12 @@ class ShardedBackend:
             out_specs=P(None, None))(q, p, ps)
 
     # -- steps 3+4 fused per shard (only when the base is fused) ----------
+    def _kernel_plan(self, batch: int, read_len: int, num_prototypes: int
+                     ) -> dict[str, int]:
+        """The base kernel's plan on one shard's prototype slice."""
+        return self.base.kernel_plan(batch, read_len,
+                                     -(-num_prototypes // self.num_shards))
+
     def _tokens_agreement(self, tokens: jax.Array, lengths: jax.Array,
                           prototypes: jax.Array) -> jax.Array:
         """Fused encode->search per shard: tokens in, agreement out.

@@ -106,6 +106,133 @@ def test_fused_tile_plan_pads_once():
     assert plan["bs"] >= 128 and plan["s_pad"] - 300 < plan["bs"]
 
 
+# -- encoded-batch cache (calls of two chunks or more) ----------------------
+
+@pytest.mark.parametrize("double_buffer", [False, True],
+                         ids=["pipeline", "dma"])
+@pytest.mark.parametrize("dim,ngram,b,length,s,tiles,chunks", [
+    (512, 5, 16, 60, 256, {"bs": 128}, 2),             # two chunks
+    (512, 5, 24, 40, 387, {"bs": 128, "bw": 8}, 4),    # odd S, 3 batch x
+                                                       # 2 word tiles
+    (1056, 8, 16, 50, 520, {"bs": 128, "bw": 8}, 5),   # W=33, odd word tile
+    (512, 5, 16, 60, 100, {}, 1),                      # one chunk: no cache
+])
+def test_fused_encode_cache_matches_reference(dim, ngram, b, length, s,
+                                              tiles, chunks, double_buffer):
+    """Chunks after the first search with the tiles chunk 0 encoded;
+    rows with no valid gram (length 0, or under the n-gram) included."""
+    space = HDSpace(dim=dim, ngram=ngram, z_threshold=3.0)
+    plan = ops.fused_tile_plan(b, s, space.num_words, read_len=length,
+                               n=ngram, **tiles)
+    assert plan["n_chunks"] == chunks and plan["encodes"] == 1
+    rng = np.random.default_rng(chunks)
+    toks = rng.integers(0, 4, (b, length)).astype(np.int32)
+    lens = rng.integers(0, length + 1, b).astype(np.int32)
+    lens[:3] = [0, 1, ngram - 1]                       # zero valid grams
+    protos = rng.integers(0, 2 ** 32, (s, space.num_words), dtype=np.uint32)
+    np.testing.assert_array_equal(
+        _fused_agreement(space, toks, lens, protos,
+                         double_buffer=double_buffer, **tiles),
+        _reference_agreement(space, toks, lens, protos))
+
+
+def test_fused_tile_plan_sizes_the_encode_cache():
+    """The cache holds the padded batch's encoded words when the call has
+    two chunks or more, and nothing with one; the tile buffers (and so
+    ``bs`` and the chunk count) are what they were without it."""
+    w, n, read_len = 1280, 16, 256                    # D=40960, AFS20 short
+    cost = dict(read_len=read_len, n=n)
+    for b, s, chunks in [(4096, 1480, 2), (256, 1480, 2), (4093, 700, 1)]:
+        plan = ops.fused_tile_plan(b, s, w, **cost)
+        assert plan["n_chunks"] == chunks
+        assert plan["bs"] == 768
+        tiles = ops.vmem_bytes(plan, **cost)
+        assert tiles <= ops.VMEM_BUDGET
+        assert plan["n_calls"] == 1 and plan["b_call"] == plan["b_pad"]
+        assert plan["encodes"] == 1
+        if chunks == 1:
+            assert plan["cache_bytes"] == 0
+            assert plan["vmem_limit_bytes"] is None
+        else:
+            assert plan["cache_bytes"] == plan["b_pad"] * plan["w_pad"] * 4
+    short = ops.fused_tile_plan(4096, 1480, w, **cost)
+    held = ops.vmem_bytes(short, **cost) + short["cache_bytes"]
+    assert held > ops.VMEM_BUDGET                     # 20 MiB of cache
+    assert held + ops.VMEM_HEADROOM <= short["vmem_limit_bytes"] \
+        <= ops.vmem_capacity() - ops.VMEM_HEADROOM
+    ont = ops.fused_tile_plan(256, 1480, w, read_len=4096, n=n)
+    assert ops.vmem_bytes(ont, read_len=4096, n=n) + ont["cache_bytes"] \
+        <= ops.VMEM_BUDGET and ont["vmem_limit_bytes"] is None
+
+
+def test_fused_batch_split_when_cache_exceeds_vmem(monkeypatch):
+    """A batch whose cache cannot fit the chip's VMEM runs as several
+    kernel calls, each streaming the prototypes once: bit-exact."""
+    space = HDSpace(dim=512, ngram=5, z_threshold=3.0)
+    b, length, s = 40, 44, 260
+    tiles = {"bs": 128}
+    whole = ops.fused_tile_plan(b, s, space.num_words, read_len=length,
+                                n=5, **tiles)
+    assert whole["n_calls"] == 1 and whole["n_chunks"] == 3
+    # room for 16 rows of cache beside the tile buffers
+    room = (ops.vmem_bytes(whole, read_len=length, n=5)
+            + 2 * ops.VMEM_HEADROOM + 16 * whole["w_pad"] * 4)
+    monkeypatch.setattr(ops, "vmem_capacity", lambda: room)
+    plan = ops.fused_tile_plan(b, s, space.num_words, read_len=length,
+                               n=5, **tiles)
+    assert (plan["n_calls"], plan["b_call"], plan["b_pad"]) == (3, 16, 48)
+    assert plan["cache_bytes"] == 16 * plan["w_pad"] * 4
+    rng = np.random.default_rng(5)
+    toks = rng.integers(0, 4, (b, length)).astype(np.int32)
+    lens = rng.integers(0, length + 1, b).astype(np.int32)
+    protos = rng.integers(0, 2 ** 32, (s, space.num_words), dtype=np.uint32)
+    ops.fused_agreement.clear_cache()        # trace under the small VMEM
+    try:
+        got = _fused_agreement(space, toks, lens, protos, **tiles)
+    finally:
+        ops.fused_agreement.clear_cache()
+    np.testing.assert_array_equal(
+        got, _reference_agreement(space, toks, lens, protos))
+
+
+def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample):
+    """``session.dispatch`` carries the kernel plan of the call: one
+    encode per read, on one chunk and on several."""
+    from repro import obs
+
+    seen = []
+    real_span = obs.span
+
+    def recording_span(name, **args):
+        seen.append((name, args))
+        return real_span(name, **args)
+
+    monkeypatch.setattr(obs, "span", recording_span)
+    for window, bs, chunks in [(1024, 4096, 1), (128, 128, 2)]:
+        s = ProfilingSession(_config(window=window,
+                                     backend_options={"bs": bs}))
+        s.build_refdb(sample.genomes)
+        seen.clear()
+        s.classify_batch(sample.tokens[:16], sample.lengths[:16])
+        (args,) = [a for n, a in seen if n == "session.dispatch"]
+        assert args == {"path": "tokens_agreement", "chunks": chunks,
+                        "encodes": 1}
+        assert s.backend.kernel_plan(
+            16, sample.tokens.shape[1],
+            s.refdb.prototypes.shape[0]) == {"chunks": chunks, "encodes": 1}
+    # sharded over the fused kernel: the plan of one shard's slice
+    sharded = ProfilingSession(_config(
+        backend="sharded", backend_options={"base": "pallas_fused",
+                                            "bs": 128})).backend
+    per_shard = 129 * sharded.num_shards                # 2 chunks a shard
+    assert sharded.kernel_plan(16, 40, per_shard) \
+        == sharded.base.kernel_plan(16, 40, 129) == {"chunks": 2,
+                                                     "encodes": 1}
+    unfused = ProfilingSession(_config(
+        backend="sharded", backend_options={"base": "reference"})).backend
+    assert getattr(unfused, "kernel_plan", None) is None
+
+
 # -- backend + session ------------------------------------------------------
 
 def test_fused_backend_registered():

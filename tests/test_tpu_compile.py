@@ -74,25 +74,57 @@ def test_hdc_encoder_compiles(one_chip, rows, length):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("double_buffer", [True, False],
-                         ids=["dma", "pipeline"])
-def test_fused_profile_compiles(one_chip, tiles, double_buffer):
+def _compile_fused(one_chip, plan, read_len, double_buffer):
+    """Compile one fused call of ``plan`` for the described chip.
+
+    The tile buffers stay within ``VMEM_BUDGET``; with the encoded-batch
+    cache they stay within the scoped limit the call passes, and that
+    limit within the chip's VMEM.
+    """
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    plan = ops.fused_tile_plan(BATCH, PROTOTYPES, W, read_len=READ_LEN,
-                               n=SPACE.ngram, **tiles)
-    assert ops.vmem_bytes(plan, read_len=READ_LEN, n=SPACE.ngram) \
-        <= ops.VMEM_BUDGET
+    tiles = ops.vmem_bytes(plan, read_len=read_len, n=SPACE.ngram)
+    assert tiles <= ops.VMEM_BUDGET
+    limit = plan["vmem_limit_bytes"] or ops.SCOPED_VMEM_DEFAULT
+    assert tiles + plan["cache_bytes"] + ops.VMEM_HEADROOM <= limit \
+        <= ops.V5E_VMEM_CAPACITY
     compiled = _compile(
         functools.partial(fused_profile.fused_profile, n=SPACE.ngram,
                           dim=SPACE.dim, bb=plan["bb"], bw=plan["bw"],
                           bs=plan["bs"], interpret=False,
-                          double_buffer=double_buffer),
-        sds((plan["b_pad"], READ_LEN), jnp.int32),
-        sds((plan["b_pad"], 1), jnp.int32),
+                          double_buffer=double_buffer,
+                          vmem_limit_bytes=plan["vmem_limit_bytes"]),
+        sds((plan["b_call"], read_len), jnp.int32),
+        sds((plan["b_call"], 1), jnp.int32),
         sds((SPACE.ngram, 4, plan["w_pad"]), jnp.uint32),
         sds((1, plan["w_pad"]), jnp.uint32),
         sds((plan["s_pad"], plan["w_pad"]), jnp.uint32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("double_buffer", [True, False],
+                         ids=["dma", "pipeline"])
+def test_fused_profile_compiles(one_chip, tiles, double_buffer):
+    plan = ops.fused_tile_plan(BATCH, PROTOTYPES, W, read_len=READ_LEN,
+                               n=SPACE.ngram, **tiles)
+    assert plan["n_chunks"] == 2 and plan["cache_bytes"] > 0
+    _compile_fused(one_chip, plan, READ_LEN, double_buffer)
+
+
+@pytest.mark.parametrize("double_buffer", [True, False],
+                         ids=["dma", "pipeline"])
+@pytest.mark.parametrize("rows,bucket", [(BATCH, 256), (256, 4096)],
+                         ids=["short", "ont"])
+def test_fused_profile_compiles_at_cell_shapes(one_chip, tiles, rows, bucket,
+                                               double_buffer):
+    """The benchmark's cohorts: 4096 short reads in the 256 bucket (a
+    20-MiB encoded-batch cache under a raised scoped limit) and 256 long
+    reads in the 4096 bucket (a 1.25-MiB cache under the default)."""
+    plan = ops.fused_tile_plan(rows, PROTOTYPES, W, read_len=bucket,
+                               n=SPACE.ngram, **tiles)
+    assert (plan["n_chunks"], plan["s_pad"], plan["n_calls"]) == (2, 1536, 1)
+    assert plan["cache_bytes"] == rows * W * 4
+    assert (plan["vmem_limit_bytes"] is None) == (rows == 256)
+    _compile_fused(one_chip, plan, bucket, double_buffer)
 
 
 @pytest.mark.parametrize("formulation", ["matmul", "packed"])
