@@ -2,19 +2,22 @@
 
     python3 bench/control.py --workload afs20-short-open --seeds 1 2 3
 
-For each seed it makes the cell's genomes and requests, builds the plain
-reference, and puts the reference computed with its agreements held in
-bfloat16 in the program's place: cohorts of the cell's batch and bucket
-shapes and whole requests, sampled as a run samples them, profiled both
+For each seed it makes the cell's genomes and requests, builds the
+configuration's plain reference, and puts the reference computed with its
+agreements held in bfloat16 in the program's place: cohorts of the cell's
+batch, padded to the lengths the service's own scheduler gives them (one
+service is built for that alone, on the first seed, and asked for every
+seed), and whole requests, sampled as a run samples them, profiled both
 ways, compared by the same numbers a run compares and judged by the same
-limits (``correct``).  The check is sound only where this control reads
-``correct`` false on every seed.  The benchmark's own runs do not run
-this.
+limits (``correct``).  The check is
+sound only where this control reads ``correct`` false on every seed.
+The benchmark's own runs do not run this.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import pathlib
@@ -26,33 +29,43 @@ import numpy as np
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
-def readings(cfg: dict, traffic: dict, seed: int, seconds: float) -> dict:
-    """The control's compared numbers on one seed."""
+def padder(cfg: dict, traffic: dict, genomes: np.ndarray):
+    """``pad(length)``: the cohort length the program's service pads a read
+    of ``length`` bases to.  The service (with its RefDB of ``genomes``,
+    through the program's store) is built once, for its scheduler alone."""
+    from bench import harness
+
+    _, service, _ = harness.build_service(cfg, traffic, genomes,
+                                          log=lambda s: None)
+    return functools.partial(harness.padded_length, service)
+
+
+def readings(cfg: dict, traffic: dict, seed: int, seconds: float,
+             pad) -> dict:
+    """The control's compared numbers on one seed; ``pad`` is
+    :func:`padder`'s."""
     from bench import harness, loadgen
-    from bench.reference import Reference
 
     wl = loadgen.make(cfg, traffic, seed, seconds)
     sent = wl.requests[:wl.clients] if wl.loop == "closed" else wl.requests
-    ref = Reference(cfg, wl.genomes)
+    ref = harness.reference(cfg)(cfg, wl.genomes)
 
-    # Cohorts as the service's scheduler forms them: batch_size rows in
-    # arrival order, padded to the bucket of the longest read.
+    # Cohorts as the service forms them: batch_size rows in arrival order,
+    # padded to the length its own scheduler gives the longest read.
     b = cfg["batch_size"]
     lengths = np.concatenate([r.lengths for r in sent])
     width = max(r.tokens.shape[1] for r in sent)
     tokens = np.concatenate([np.pad(r.tokens, ((0, 0), (0, width
                                                         - r.tokens.shape[1])))
                              for r in sent])
-    sched = harness.scheduler(b)
-    for i, n in enumerate(lengths):
-        sched.submit(i, int(n))
     cohorts = []
-    for c in sched.drain():
-        idx = np.asarray(c.items)
+    for i in range(0, len(lengths), b):
+        idx = np.arange(i, min(i + b, len(lengths)))
+        padded = pad(lengths[idx].max())
         ln = np.zeros(b, np.int32)
         ln[:len(idx)] = lengths[idx]
-        part = tokens[idx, :c.length]
-        tk = np.zeros((b, c.length), np.int8)
+        part = tokens[idx, :padded]
+        tk = np.zeros((b, padded), np.int8)
         tk[:part.shape[0], :part.shape[1]] = part
         cohorts.append((tk, ln, None))
     cohorts = harness.sample_cohorts(cohorts, seed)
@@ -71,8 +84,8 @@ def readings(cfg: dict, traffic: dict, seed: int, seconds: float) -> dict:
             total_reads=rep["total"], unmapped_reads=rep["unmapped"],
             multi_reads=rep["multi"], unique_counts=rep["unique_counts"],
             abundance=rep["abundance"])
-    out = {"failed": 0, **harness.compare(cfg, wl.genomes, ref.prototypes,
-                                          requests, cohorts, ref=ref)}
+    out = {"failed": 0, **harness.compare(ref, ref.prototypes, requests,
+                                          cohorts)}
     out["correct"] = harness.is_correct(out)
     out["cohort_reads"] = int(sum((c[1] > 0).sum() for c in cohorts))
     out["request_reads"] = sum(r["report"].total_reads for r in requests)
@@ -90,9 +103,12 @@ def main(argv: list[str] | None = None) -> int:
     import jax
     jax.config.update("jax_compilation_cache_dir", str(harness.COMPILE_CACHE))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    from bench import loadgen
     cfg, traffic = harness.cell_files(harness.manifest(), args.workload)
+    pad = padder(cfg, traffic, loadgen.make(cfg, traffic, args.seeds[0],
+                                            args.seconds).genomes)
     for seed in args.seeds:
-        r = readings(cfg, traffic, seed, args.seconds)
+        r = readings(cfg, traffic, seed, args.seconds, pad)
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "device": jax.devices()[0].device_kind,
                           **r}), flush=True)

@@ -4,19 +4,23 @@
 :func:`run_cell`.  Everything that belongs to one cell is found by name:
 the manifest (``BENCHMARK.json``) names the cell's configuration and
 traffic; ``bench/configs/<file>.json`` and ``bench/traffic/<traffic>.json``
-hold their parameters; ``bench/metrics/<metric>.py`` reads each metric.
+hold their parameters; ``bench/metrics/<metric>.py`` reads each metric;
+and the configuration's ``reference`` key names its plain reference,
+``bench/<reference>``, whose ``Reference`` class the check uses.
 
-A run: make the genomes and requests from the seed, load the RefDB
+A run: load the configuration's reference (a missing or broken file ends
+set-up), make the genomes and requests from the seed, load the RefDB
 through the program's content-keyed store (a seed's first run builds it
-there first, timed apart from set-up), warm the cohort shapes
-the cell's reads can take, start a ``ProfilingService`` with its
+there first, timed apart from set-up), build the ``ProfilingService``,
+warm each cohort length its own scheduler pads the cell's reads to (a
+read it refuses ends set-up with :class:`SetupError`), start its
 background worker, and drive it for ``seconds`` (open loop: requests sent
 at their due times; closed loop: a fixed number of requests kept in
-flight).  Then the program's state is freed and the plain reference
-(``bench/reference.py``) checks a seeded sample of what the service
-answered.  With ``trace`` on, the window runs under ``jax.profiler`` with
-spans around the benchmark's calls into the service, and the per-layer
-metrics are read from that trace.
+flight).  Then the program's state is freed and the reference checks a
+seeded sample of what the service answered.  With ``trace`` on, the
+window runs under ``jax.profiler`` with spans around the benchmark's
+calls into the service, and the per-layer metrics are read from that
+trace.
 """
 
 from __future__ import annotations
@@ -57,6 +61,11 @@ def is_correct(checked: dict[str, float]) -> bool:
     return all(checked[k] <= LIMITS[k] for k in LIMITS)
 
 
+class SetupError(Exception):
+    """Set-up cannot go on; ``run.py`` prints the message as one line and
+    exits non-zero before any window opens."""
+
+
 # -- what the manifest names -------------------------------------------------
 
 def manifest(root: pathlib.Path = ROOT) -> dict:
@@ -83,14 +92,35 @@ def cell_metrics(man: dict, name: str, trace: bool) -> list[dict]:
     return [m for m in group if name in m.get("workloads", [name])]
 
 
-def reader(name: str, root: pathlib.Path = ROOT):
-    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+def _module(path: pathlib.Path, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, root: pathlib.Path = ROOT):
+    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_" + name).read
+
+
+def reference(cfg: dict, root: pathlib.Path = ROOT):
+    """The ``Reference`` class of ``bench/<cfg["reference"]>``.
+
+    ``Reference(cfg, genomes)`` holds the RefDB's ``prototypes`` and the
+    species' prototype ``bounds``, and gives ``scores``, ``classify`` and
+    ``report`` as ``bench/reference.py`` does.  A file that is missing,
+    does not load or has no such class raises :class:`SetupError`.
+    """
+    path = root / "bench" / cfg["reference"]
+    try:
+        return _module(path, "bench_reference_" + path.stem).Reference
+    except Exception as e:
+        raise SetupError(
+            f"bench: configuration {cfg['name']!r} has no usable reference "
+            f"{cfg['reference']!r}: {e!r}") from e
 
 
 # -- what one run records ----------------------------------------------------
@@ -130,18 +160,68 @@ def nearest_rank(values: list[float], pct: float) -> float:
     return v[max(0, math.ceil(pct / 100 * len(v)) - 1)]
 
 
-def scheduler(batch_size: int):
-    """A cohort scheduler shaped as ``ProfilingService``'s default one."""
-    from repro.serve.scheduler import FixedShapeScheduler, pow2_buckets
-    return FixedShapeScheduler(slots=batch_size,
-                               buckets=pow2_buckets(16, 4096))
+def padded_length(service, length: int) -> int:
+    """The cohort length ``service``'s own scheduler pads a read of
+    ``length`` bases to; :class:`SetupError` where it refuses the read.
+
+    The service has no public accessor for its buckets yet, so this reads
+    its scheduler (``_sched.bucket_for``, ``_sched.buckets``); a service
+    without them is a :class:`SetupError` too, naming what is missing.
+    """
+    try:
+        sched = service._sched
+        bucket_for, buckets = sched.bucket_for, sched.buckets
+    except AttributeError as e:
+        raise SetupError(
+            f"bench: the service's scheduler has no bucket_for/buckets "
+            f"to pad reads with: {e}") from None
+    try:
+        return bucket_for(max(int(length), 1))
+    except ValueError:
+        raise SetupError(
+            f"bench: the service refuses reads of {int(length)} bp: its "
+            f"largest bucket is {buckets[-1]}") from None
 
 
-def _buckets(requests) -> list[int]:
-    """Cohort lengths the service can pad this cell's reads to."""
-    sched = scheduler(1)
-    return sorted({sched.bucket_for(int(x))
-                   for r in requests for x in np.unique(r.lengths)})
+def warm_lengths(service, requests) -> list[int]:
+    """Every cohort length the service can pad this cell's reads to; a
+    refused read raises :class:`SetupError` naming the longest."""
+    lengths = np.unique(np.concatenate([r.lengths for r in requests]))
+    return sorted({padded_length(service, x) for x in lengths[::-1]})
+
+
+def build_service(cfg: dict, traffic: dict, genomes: np.ndarray,
+                  log=print):
+    """The program's session, with its RefDB of ``genomes``, and its
+    service, not started; with the RefDB build's seconds, ``None`` where
+    the store held it already."""
+    from repro.core import HDSpace
+    from repro.pipeline import ProfilerConfig, ProfilingSession
+    from repro.serve import ProfilingService
+
+    names = [f"species_{s:02d}" for s in range(len(genomes))]
+    config = ProfilerConfig(
+        space=HDSpace(dim=cfg["dim"], ngram=cfg["ngram"],
+                      alphabet_size=cfg["alphabet"],
+                      z_threshold=cfg["z_threshold"], seed=cfg["space_seed"]),
+        window=cfg["window"], batch_size=cfg["batch_size"],
+        backend=cfg["backend"])
+    session = ProfilingSession(config)
+    # A deployment builds its RefDB once, offline, and loads it at start:
+    # on a seed's first run the build is timed apart and left out of
+    # set-up, and every run then loads the RefDB from the store.
+    named = {n: g.astype(np.int32) for n, g in zip(names, genomes)}
+    build_s, t_build = None, time.perf_counter()
+    db = session.build_or_load_refdb(named, cache_dir=STORE)
+    if not session.refdb_loaded_from_cache:
+        build_s = time.perf_counter() - t_build
+        db = session.build_or_load_refdb(named, cache_dir=STORE)
+        log(f"refdb built in {build_s} s (not in setup_s)")
+    log(f"refdb loaded: {db.num_prototypes} prototypes, "
+        f"{db.memory_bytes()} bytes")
+    service = ProfilingService(session, max_active=traffic["max_active"],
+                               max_queue=traffic["max_queue"])
+    return session, service, build_s
 
 
 class _Recorder:
@@ -348,18 +428,16 @@ def sample_cohorts(cohorts, seed: int) -> list:
     return _sample(cohorts, lambda c: int(np.asarray(c[1]).sum()), seed, 98)
 
 
-def compare(cfg: dict, genomes: np.ndarray, protos: np.ndarray, requests,
-            cohorts, ref=None) -> dict[str, float]:
+def compare(ref, protos: np.ndarray, requests, cohorts) -> dict[str, float]:
     """Re-profile sampled cohorts and requests with the plain reference.
 
-    ``cohorts`` are ``(tokens, lengths, scores)`` as the service ran them:
-    every live read's score for every species must equal the reference's.
-    ``requests`` carry the service's report, which must equal the
-    reference's report of the same reads.
+    ``ref`` is the configuration's :func:`reference`, built for the run's
+    genomes; ``protos`` the program's RefDB.  ``cohorts`` are ``(tokens,
+    lengths, scores)`` as the service ran them: every live read's score
+    for every species must equal the reference's.  ``requests`` carry the
+    service's report, which must equal the reference's report of the same
+    reads.
     """
-    from bench.reference import Reference
-
-    ref = ref or Reference(cfg, genomes)
     out = {"score_diff": 0, "count_diff": 0, "abundance_diff": 0.0,
            "prototype_diff": int((ref.prototypes != protos).sum())}
     for tokens, lengths, scores in cohorts:
@@ -386,48 +464,33 @@ def compare(cfg: dict, genomes: np.ndarray, protos: np.ndarray, requests,
 
 def run_cell(*, cell: str, cfg: dict, traffic: dict, seed: int,
              seconds: float, trace: bool, t_start: float, metrics: list[dict],
-             log=print) -> tuple[dict, Run]:
-    """Set up, drive the window, check; the result line and the run."""
+             log=print, root: pathlib.Path = ROOT) -> tuple[dict, Run]:
+    """Set up, drive the window, check; the result line and the run.
+
+    The reference and the metric readers are found under ``root``.
+    Raises :class:`SetupError`, before any window, where the configuration
+    has no usable reference or the service refuses one of the cell's
+    read lengths.
+    """
     import jax
-    from repro.core import HDSpace
-    from repro.pipeline import ProfilerConfig, ProfilingSession
-    from repro.serve import ProfilingService
 
     device = jax.devices()[0]
     run = Run(cfg=cfg, traffic=traffic, device_kind=device.device_kind)
     phases = {"start": time.perf_counter() - t_start}
+    Reference = reference(cfg, root)
     wl = loadgen.make(cfg, traffic, seed, seconds)
     phases["data"] = time.perf_counter() - t_start
-    names = [f"species_{s:02d}" for s in range(len(wl.genomes))]
-    genomes = {n: g.astype(np.int32) for n, g in zip(names, wl.genomes)}
-    config = ProfilerConfig(
-        space=HDSpace(dim=cfg["dim"], ngram=cfg["ngram"],
-                      alphabet_size=cfg["alphabet"],
-                      z_threshold=cfg["z_threshold"], seed=cfg["space_seed"]),
-        window=cfg["window"], batch_size=cfg["batch_size"],
-        backend=cfg["backend"])
-    session = ProfilingSession(config)
-    # A deployment builds its RefDB once, offline, and loads it at start:
-    # on a seed's first run the build is timed apart and left out of
-    # set-up, and every run then loads the RefDB from the store.
-    t_build = time.perf_counter()
-    db = session.build_or_load_refdb(genomes, cache_dir=STORE)
-    if not session.refdb_loaded_from_cache:
-        run.build_s = time.perf_counter() - t_build
-        db = session.build_or_load_refdb(genomes, cache_dir=STORE)
-        log(f"refdb built in {run.build_s} s (not in setup_s)")
-    log(f"refdb loaded: {db.num_prototypes} prototypes, "
-        f"{db.memory_bytes()} bytes")
+    session, service, run.build_s = build_service(cfg, traffic, wl.genomes,
+                                                  log)
+    db = session.refdb
     run.prototypes, run.species = db.num_prototypes, db.num_species
     phases["refdb"] = time.perf_counter() - t_start
     b = cfg["batch_size"]
-    for length in _buckets(wl.requests):
+    for length in warm_lengths(service, wl.requests):
         res = session.classify_batch(np.zeros((b, length), np.int32),
                                      np.full(b, length, np.int32))
         np.asarray(res.classification.hits)
         np.asarray(res.classification.category)
-    service = ProfilingService(session, max_active=traffic["max_active"],
-                               max_queue=traffic["max_queue"])
     recorder = _Recorder(service, session, trace)
     trace_dir = TRACES / cell
     if trace:
@@ -471,6 +534,12 @@ def run_cell(*, cell: str, cfg: dict, traffic: dict, seed: int,
             f"{worst['req'].due_s} s), p95 {nearest_rank(late, 95)} s, "
             f"median {nearest_rank(late, 50)} s over {len(late)} requests; "
             f"longest submit() {max(r['submit_s'] for r in recs)} s")
+    if len(run.finished) > 1:
+        ends = np.array([t for t, _ in run.finished])
+        gaps = np.diff(ends)
+        log(f"window: {len(ends)} cohorts finished by {ends[-1]} s; between "
+            f"cohort ends median {np.median(gaps)} s, longest {gaps.max()} s "
+            f"(ending at {ends[1:][gaps.argmax()]} s)")
     stats = device.memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     protos = np.asarray(db.prototypes)
@@ -483,7 +552,8 @@ def run_cell(*, cell: str, cfg: dict, traffic: dict, seed: int,
     checked = {"failed": failed}
     t_check = time.perf_counter()
     sample = sample_requests(recs, seed)
-    checked.update(compare(cfg, wl.genomes, protos, sample, cohorts))
+    checked.update(compare(Reference(cfg, wl.genomes), protos, sample,
+                           cohorts))
     log(f"check: reference re-profiled {len(cohorts)} cohorts and "
         f"{len(sample)} requests ({sum(r['report'].total_reads for r in sample)}"
         f" reads) in {time.perf_counter() - t_check} s")
@@ -493,7 +563,7 @@ def run_cell(*, cell: str, cfg: dict, traffic: dict, seed: int,
         run.trace = trace_reduce.load(trace_dir)
     values = {}
     for m in metrics:
-        v = reader(m["name"])(run)
+        v = reader(m["name"], root)(run)
         if v is not None:
             values[m["name"]] = {"value": v, "unit": m["unit"]}
     result = {"correct": correct, "attempted": len(recs), "failed": failed,

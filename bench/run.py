@@ -11,8 +11,11 @@ a ``breakdown``, and last ``compared``: each number the correctness check
 compared, beside its limit.  The same numbers close standard error.
 
 It exits non-zero, printing no result, unless JAX's devices are TPUs
-and there are as many as the cell asks for.  JAX's persistent compile
-cache lives in ``bench/.jax_cache`` of the checkout.
+and there are as many as the cell asks for; and with one line on
+standard error where set-up cannot go on: the configuration has no
+usable reference file, or the service refuses one of the cell's reads.
+JAX's persistent compile cache lives in ``bench/.jax_cache`` of the
+checkout.
 """
 
 from __future__ import annotations
@@ -60,11 +63,15 @@ def main(argv: list[str] | None = None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
     cfg, traffic = harness.cell_files(man, args.workload)
-    result, _ = harness.run_cell(
-        cell=args.workload, cfg=cfg, traffic=traffic, seed=args.seed,
-        seconds=args.seconds, trace=bool(args.trace), t_start=T_START,
-        metrics=harness.cell_metrics(man, args.workload, bool(args.trace)),
-        log=lambda s: print(s, flush=True))
+    try:
+        result, _ = harness.run_cell(
+            cell=args.workload, cfg=cfg, traffic=traffic, seed=args.seed,
+            seconds=args.seconds, trace=bool(args.trace), t_start=T_START,
+            metrics=harness.cell_metrics(man, args.workload,
+                                         bool(args.trace)),
+            log=lambda s: print(s, flush=True))
+    except harness.SetupError as e:
+        sys.exit(str(e))
     for name, c in result["compared"].items():
         print(f"compared {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr)

@@ -14,7 +14,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
-from bench import control, harness  # noqa: E402
+from bench import control, harness, loadgen  # noqa: E402
 
 SEED = 3_000_000_777
 
@@ -106,10 +106,13 @@ def test_refused_request_is_not_correct(tmp_path, monkeypatch):
     assert not result["correct"]
 
 
-def test_control_in_bfloat16_is_not_correct():
+def test_control_in_bfloat16_is_not_correct(tmp_path, monkeypatch):
+    monkeypatch.setattr(harness, "STORE", tmp_path / "store")
     cfg, traffic = _tiny()
+    pad = control.padder(cfg, traffic, loadgen.make(cfg, traffic, 1,
+                                                    2.0).genomes)
     for seed in (1, 2, 3):
-        got = control.readings(cfg, traffic, seed, 2.0)
+        got = control.readings(cfg, traffic, seed, 2.0, pad)
         assert got["score_diff"] > 0
         assert got["prototype_diff"] == 0
         assert not got["correct"]

@@ -60,6 +60,26 @@ def test_every_metric_has_a_reader(man):
         assert callable(harness.reader(m["name"]))
 
 
+def test_short_backlog_cell_reports_throughput(man):
+    """Eight 50,000-read short-read samples in a closed loop, on AFS20 as
+    the open cell runs it, read as throughput and its four layers."""
+    cell = {w["name"]: w for w in man["workloads"]}["afs20-short-backlog"]
+    assert (cell["config"], cell["chips"]) == ("afs20", 1)
+    cfg, traffic = harness.cell_files(man, "afs20-short-backlog")
+    assert cfg == harness.cell_files(man, "afs20-short-open")[0]
+    assert {k: traffic[k] for k in ("loop", "clients", "request_reads",
+                                    "max_active", "max_queue")} == {
+        "loop": "closed", "clients": 8,
+        "request_reads": {"dist": "fixed", "value": 50000},
+        "max_active": 8, "max_queue": 64}
+    assert {m["name"] for m in harness.cell_metrics(
+        man, "afs20-short-backlog", False)} == {"reads_per_s", "setup_s"}
+    assert {m["name"] for m in harness.cell_metrics(
+        man, "afs20-short-backlog", True)} == {
+        "fused_ms_per_kread.backlog", "fused_roofline_pct.backlog",
+        "device_idle_pct.backlog", "token_fill_pct.backlog"}
+
+
 def test_new_config_traffic_and_metric_are_found_by_name(tmp_path, man):
     """A later change adds a cell by adding files and manifest entries."""
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
