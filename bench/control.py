@@ -5,7 +5,7 @@
 For each seed it makes the cell's genomes and requests, builds the
 configuration's plain reference, and puts the reference computed with its
 agreements held in bfloat16 in the program's place: cohorts of the cell's
-batch, padded to the lengths the service's own scheduler gives them (one
+batch, padded to the lengths the program's service gives them (one
 service is built for that alone, on the first seed, and asked for every
 seed), and whole requests, sampled as a run samples them, profiled both
 ways, compared by the same numbers a run compares and judged by the same
@@ -32,7 +32,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 def padder(cfg: dict, traffic: dict, genomes: np.ndarray):
     """``pad(length)``: the cohort length the program's service pads a read
     of ``length`` bases to.  The service (with its RefDB of ``genomes``,
-    through the program's store) is built once, for its scheduler alone."""
+    through the program's store) is built once, for its buckets alone."""
     from bench import harness
 
     _, service, _ = harness.build_service(cfg, traffic, genomes,
@@ -51,7 +51,7 @@ def readings(cfg: dict, traffic: dict, seed: int, seconds: float,
     ref = harness.reference(cfg)(cfg, wl.genomes)
 
     # Cohorts as the service forms them: batch_size rows in arrival order,
-    # padded to the length its own scheduler gives the longest read.
+    # padded to the length the service gives the longest read.
     b = cfg["batch_size"]
     lengths = np.concatenate([r.lengths for r in sent])
     width = max(r.tokens.shape[1] for r in sent)

@@ -12,7 +12,7 @@ A run: load the configuration's reference (a missing or broken file ends
 set-up), make the genomes and requests from the seed, load the RefDB
 through the program's content-keyed store (a seed's first run builds it
 there first, timed apart from set-up), build the ``ProfilingService``,
-warm each cohort length its own scheduler pads the cell's reads to (a
+warm each cohort length the service pads the cell's reads to (a
 read it refuses ends set-up with :class:`SetupError`), start its
 background worker, and drive it for ``seconds`` (open loop: requests sent
 at their due times; closed loop: a fixed number of requests kept in
@@ -161,20 +161,23 @@ def nearest_rank(values: list[float], pct: float) -> float:
 
 
 def padded_length(service, length: int) -> int:
-    """The cohort length ``service``'s own scheduler pads a read of
-    ``length`` bases to; :class:`SetupError` where it refuses the read.
+    """The cohort length ``service`` pads a read of ``length`` bases to;
+    :class:`SetupError` where it refuses the read.
 
-    The service has no public accessor for its buckets yet, so this reads
-    its scheduler (``_sched.bucket_for``, ``_sched.buckets``); a service
-    without them is a :class:`SetupError` too, naming what is missing.
+    It asks the service's public ``bucket_for`` and ``buckets`` where it
+    has both, else its scheduler's (``_sched.bucket_for``,
+    ``_sched.buckets``); where neither has them, :class:`SetupError` too.
     """
-    try:
-        sched = service._sched
-        bucket_for, buckets = sched.bucket_for, sched.buckets
-    except AttributeError as e:
+    for owner in (service, getattr(service, "_sched", None)):
+        try:
+            bucket_for, buckets = owner.bucket_for, owner.buckets
+            break
+        except AttributeError:
+            continue
+    else:
         raise SetupError(
-            f"bench: the service's scheduler has no bucket_for/buckets "
-            f"to pad reads with: {e}") from None
+            "bench: neither the service nor its scheduler has "
+            "bucket_for/buckets to pad reads with")
     try:
         return bucket_for(max(int(length), 1))
     except ValueError:
@@ -437,6 +440,12 @@ def compare(ref, protos: np.ndarray, requests, cohorts) -> dict[str, float]:
     for every species must equal the reference's.  ``requests`` carry the
     service's report, which must equal the reference's report of the same
     reads.
+
+    A report covers a prefix of its request's reads: one of ``k`` reads
+    (``total_reads``), a final report or a window-end ``snapshot()``, is
+    compared with the reference's report of the request's first ``k``
+    reads in the order they were sent, so a service that classifies reads
+    out of order must still commit each request's results in read order.
     """
     out = {"score_diff": 0, "count_diff": 0, "abundance_diff": 0.0,
            "prototype_diff": int((ref.prototypes != protos).sum())}

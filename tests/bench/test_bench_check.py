@@ -7,6 +7,7 @@ import dataclasses
 import json
 import pathlib
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -116,3 +117,51 @@ def test_control_in_bfloat16_is_not_correct(tmp_path, monkeypatch):
         assert got["score_diff"] > 0
         assert got["prototype_diff"] == 0
         assert not got["correct"]
+
+
+@pytest.fixture(scope="module")
+def reference_and_request():
+    """The real reference at the tiny sizes, the largest tiny request, and
+    its reads' classification by that reference."""
+    cfg, traffic = _tiny()
+    wl = loadgen.make(cfg, traffic, SEED, 2.0)
+    req = max(wl.requests, key=lambda r: r.reads)
+    ref = harness.reference(cfg)(cfg, wl.genomes)
+    return ref, req, *ref.classify(req.tokens, req.lengths)
+
+
+def _covered(hits, cat, which: str) -> np.ndarray:
+    """Indices of ``k`` = half of the request's reads: its first ``k``
+    (``prefix``), or the first ``k - 1`` and then, in place of read
+    ``k - 1``, the first later read whose category or unique species
+    differs from it (``other``), so the two sets' species counts differ."""
+    k = len(cat) // 2
+    if which == "prefix":
+        return np.arange(k)
+
+    def key(i):
+        return int(cat[i]), tuple(hits[i]) if cat[i] == 1 else ()
+    swap = next(j for j in range(k, len(cat)) if key(j) != key(k - 1))
+    return np.append(np.arange(k - 1), swap)
+
+
+@pytest.mark.parametrize("which,count_diff_is_0", [("prefix", True),
+                                                   ("other", False)])
+def test_report_is_checked_against_its_requests_first_reads(
+        reference_and_request, which, count_diff_is_0):
+    """A report of ``k`` reads stands for the request's first ``k``: one
+    that covers other reads, of the same number, fails ``count_diff``."""
+    ref, req, hits, cat = reference_and_request
+    idx = _covered(hits, cat, which)
+    rep = ref.report(hits[idx], cat[idx])
+    first = ref.report(hits[:len(idx)], cat[:len(idx)])
+    assert (np.array_equal(rep["unique_counts"], first["unique_counts"])
+            and rep["unmapped"] == first["unmapped"]) == count_diff_is_0
+    report = types.SimpleNamespace(
+        total_reads=rep["total"], unmapped_reads=rep["unmapped"],
+        multi_reads=rep["multi"], unique_counts=rep["unique_counts"],
+        abundance=rep["abundance"])
+    got = harness.compare(ref, ref.prototypes,
+                          [{"req": req, "report": report}], [])
+    assert (got["count_diff"] == 0) == count_diff_is_0
+    assert got["score_diff"] == 0 and got["prototype_diff"] == 0

@@ -5,6 +5,7 @@ CPU, with the chip look skipped."""
 
 from __future__ import annotations
 
+import functools
 import json
 import pathlib
 import shutil
@@ -71,6 +72,14 @@ def _long_copy(tmp_path: pathlib.Path) -> dict:
     return cfg
 
 
+def _with_buckets(monkeypatch, buckets) -> None:
+    """Every service ``harness.build_service`` builds gets ``buckets``
+    through its public constructor, whatever the program's default."""
+    from repro import serve
+    monkeypatch.setattr(serve, "ProfilingService", functools.partial(
+        serve.ProfilingService, buckets=buckets))
+
+
 def _tiny(cfg: dict, traffic: dict) -> tuple[dict, dict]:
     return ({**cfg, "species": 4, "genome_len": 20_000, "dim": 1024,
              "window": 2048, "batch_size": 8},
@@ -121,11 +130,10 @@ def test_service_with_longer_buckets_is_warmed_at_its_own_lengths(
         tmp_path, monkeypatch):
     """A service whose buckets reach 16,384 is asked for that length."""
     from repro.pipeline.session import ProfilingSession
-    from repro.serve import profiler_service, scheduler
+    from repro.serve import pow2_buckets
     cfg, traffic = _tiny(_long_copy(tmp_path), json.loads(
         (ROOT / "bench/traffic/ont_backlog.json").read_text()))
-    monkeypatch.setattr(profiler_service, "pow2_buckets",
-                        lambda lo, hi: scheduler.pow2_buckets(lo, 16384))
+    _with_buckets(monkeypatch, pow2_buckets(16, 16384))
     warmed = []
 
     def record(self, tokens, lengths, **kw):
@@ -145,12 +153,15 @@ def test_service_with_longer_buckets_is_warmed_at_its_own_lengths(
 
 def test_default_service_stops_set_up_on_a_read_it_refuses(
         tmp_path, monkeypatch):
-    """Today's service refuses reads over 4,096 bp: set-up ends with one
-    line naming the longest read and the largest bucket, before any
-    warm-up or window."""
+    """A service that refuses a read stops set-up: built with buckets up
+    to 4,096 (set here, not the program's default), it refuses the
+    16,384-bp reads, and set-up ends with one line naming the longest read
+    and the largest bucket, before any warm-up or window."""
     from repro.pipeline.session import ProfilingSession
+    from repro.serve import pow2_buckets
     cfg, traffic = _tiny(_long_copy(tmp_path), json.loads(
         (ROOT / "bench/traffic/ont_backlog.json").read_text()))
+    _with_buckets(monkeypatch, pow2_buckets(16, 4096))
 
     def no_warm_up(*a, **kw):
         raise AssertionError("warm-up ran")
@@ -181,15 +192,39 @@ def test_unusable_reference_stops_set_up_first(tmp_path, monkeypatch, name,
         _run_until_window(tmp_path, monkeypatch, cfg, {})
 
 
-@pytest.mark.parametrize("cell,want", [
-    ("afs20-short-open", [256]),
-    ("afs20-ont-backlog", [1024, 2048, 4096]),
-    ("afs20-short-backlog", [256]),
-])
+def _served_lengths(service, session, lengths) -> dict[int, int]:
+    """The cohort length ``service`` runs a read of each of ``lengths``
+    at: one single-read request at a time, pumped on this thread, with the
+    session's ``classify_batch`` recording the width it is called with."""
+    from repro.pipeline import ArraySource
+    species = session.refdb.num_species
+    widths = []
+
+    def record(tokens, lens, **kw):
+        widths.append(np.shape(tokens)[1])
+        n = len(lens)
+        return types.SimpleNamespace(classification=types.SimpleNamespace(
+            hits=np.zeros((n, species), bool),
+            category=np.zeros(n, np.int32)))
+    session.classify_batch = record
+    served = {}
+    for x in lengths:
+        service.submit(ArraySource(np.zeros((1, x), np.int8),
+                                   np.array([x], np.int32)))
+        service.run_until_idle()
+        served[int(x)] = widths[-1]
+    return served
+
+
+@pytest.mark.parametrize("cell", ["afs20-short-open", "afs20-ont-backlog",
+                                  "afs20-short-backlog"])
 def test_cells_compare_through_reference_py_and_warm_as_before(
-        tmp_path, monkeypatch, cell, want):
-    """The cells already there keep ``bench/reference.py`` and warm the
-    lengths the harness warmed before it asked the service."""
+        tmp_path, monkeypatch, cell):
+    """The cells already there keep ``bench/reference.py`` and warm
+    exactly the lengths the built service runs their reads at, as the
+    service shows them (not a list written here: the buckets are the
+    program's to choose); ``padded_length`` agrees read length by read
+    length."""
     cfg, traffic = harness.cell_files(harness.manifest(), cell)
     assert harness.reference(cfg).__init__.__code__.co_filename == str(
         ROOT / "bench/reference.py")
@@ -197,17 +232,58 @@ def test_cells_compare_through_reference_py_and_warm_as_before(
             "window": 2048, "batch_size": 8}
     monkeypatch.setattr(harness, "STORE", tmp_path / "store")
     wl = loadgen.make(tiny, traffic, SEED, 35.0)
-    _, service, _ = harness.build_service(tiny, traffic, wl.genomes,
-                                          log=lambda s: None)
-    assert harness.warm_lengths(service, wl.requests) == want
+    session, service, _ = harness.build_service(tiny, traffic, wl.genomes,
+                                                log=lambda s: None)
+    warm = harness.warm_lengths(service, wl.requests)
+    lengths = np.unique(np.concatenate([r.lengths for r in wl.requests]))
+    served = _served_lengths(service, session, lengths)
+    assert warm == sorted(set(served.values()))
+    assert {x: harness.padded_length(service, x) for x in served} == served
 
 
 @pytest.mark.parametrize("service", [
     types.SimpleNamespace(),
     types.SimpleNamespace(_sched=types.SimpleNamespace(buckets=(256,))),
+    types.SimpleNamespace(bucket_for=lambda size: 256),
 ])
 def test_service_without_its_bucket_lookup_stops_set_up(service):
-    """A service whose scheduler no longer offers ``bucket_for`` and
-    ``buckets`` ends set-up with one line, not an ``AttributeError``."""
+    """A service that offers ``bucket_for`` and ``buckets`` neither
+    itself nor through its scheduler ends set-up with one line, not an
+    ``AttributeError``."""
     with pytest.raises(harness.SetupError, match="bucket_for/buckets"):
         harness.padded_length(service, 150)
+
+
+class _Buckets:
+    """A bucket lookup: the smallest of ``buckets`` that holds a read."""
+
+    def __init__(self, *buckets: int):
+        self.buckets = buckets
+
+    def bucket_for(self, size: int) -> int:
+        for b in self.buckets:
+            if size <= b:
+                return b
+        raise ValueError(f"{size} exceeds {self.buckets[-1]}")
+
+
+def _public_over_private() -> types.SimpleNamespace:
+    """A service with public buckets and a scheduler whose buckets
+    differ, so each answer shows which of the two was asked."""
+    public = _Buckets(512, 1024, 8192)
+    return types.SimpleNamespace(bucket_for=public.bucket_for,
+                                 buckets=public.buckets,
+                                 _sched=_Buckets(256, 4096))
+
+
+@pytest.mark.parametrize("length,want", [(150, 512), (700, 1024),
+                                         (5000, 8192)])
+def test_padded_length_prefers_the_services_public_buckets(length, want):
+    assert harness.padded_length(_public_over_private(), length) == want
+
+
+def test_read_refused_by_the_public_buckets_stops_set_up():
+    with pytest.raises(harness.SetupError) as e:
+        harness.padded_length(_public_over_private(), 9000)
+    assert str(e.value) == ("bench: the service refuses reads of 9000 bp: "
+                            "its largest bucket is 8192")
