@@ -48,7 +48,19 @@ inside the memristor array):
   so the scratch outlives the cells.  A one-chunk call has nothing to
   reuse and allocates no cache.
 
-Per grid cell ``(k, i, j)``:
+* **Length-bounded batch tiles.**  A tile's work follows its reads'
+  lengths, not the padded shape.  :func:`tile_trips` gives each ``bb``-row
+  tile its longest row's gram count, ``max(0, max(len) - n + 1)``, which
+  reaches the kernel as a scalar-prefetch operand and bounds its gram
+  loop in place of the static ``L - n + 1`` (grams past it are masked on
+  every row of the tile, so the words are the same).  A tile with none
+  to run — every row padding or shorter than ``n`` — encodes every row
+  to the tie-break vector, so its cells skip the encode, the cache store
+  and the search, and its last word tile writes :func:`tie_agreement`'s
+  ``(1, S)`` row, computed once per call, to each row.  A tile of
+  full-length rows runs today's loop count.
+
+Per grid cell ``(k, i, j)`` of a batch tile with grams to run:
 
   1. **Encode** (chunk 0, or every cell of a one-chunk call) the
      ``(bb, bw)`` word tile with the encoder kernel's own
@@ -62,7 +74,8 @@ Per grid cell ``(k, i, j)``:
      the persistent ``(bb, bs)`` Hamming scratch.
   3. On the last word tile, flush ``agreement = dim - hamming`` into the
      ``(i, k)`` output block — the only HBM write of the whole query
-     path besides the final scores.
+     path besides the final scores.  (A tile with none flushes the tie
+     row.)
 
 The word axis is innermost ("arbitrary": it carries the accumulator);
 the IM, tie, and prototype arrays arrive word-split with the word tile
@@ -97,12 +110,36 @@ from repro.kernels.hdc_encoder import (LANES, WORD_BITS, encode_tile,
 from repro.kernels.interpret import interpret_default
 
 
+def tile_trips(lengths, *, bb: int, n: int, g: int):
+    """Grams the encode runs for each ``bb``-row batch tile.
+
+    A tile's longest row sets it: ``max(0, max(len) - n + 1)``, at most
+    ``g``.  ``lengths`` is ``(B,)`` or ``(B, 1)`` with ``B % bb == 0``,
+    a numpy or a jax array (the kernel computes it on the device, the
+    session's counter on the host); returns ``(B / bb,)``.
+    """
+    return (lengths.reshape(-1, bb).max(axis=1) - (n - 1)).clip(0, g)
+
+
+def tie_agreement(tie: jax.Array, p_packed: jax.Array, dim: int
+                  ) -> jax.Array:
+    """``(1, S)`` agreement of the tie-break vector with every prototype.
+
+    A row with no valid gram encodes to the tie-break vector (every
+    counter ties), so this is the score row of every row of a batch tile
+    whose trip count is 0.
+    """
+    x = jnp.bitwise_xor(tie, p_packed)
+    return dim - jnp.bitwise_count(x).astype(jnp.int32).sum(axis=1)[None, :]
+
+
 def _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
-                k, i, j, *, n: int, alphabet: int, g: int) -> jax.Array:
+                k, i, j, trip, *, n: int, alphabet: int) -> jax.Array:
     """Encoded word tile ``j`` of batch tile ``i``: ``(bb, bw)`` uint32.
 
     ``im_ref``/``tie_ref`` are the word-split ``(W/bw, n*alphabet, bw)``
     / ``(W/bw, 1, bw)`` views; ``j`` picks the tile on the leading dim.
+    The gram loop runs ``trip`` grams, the tile's longest row's.
     With no cache (one chunk) every cell encodes.  With one, the cells of
     chunk ``k == 0`` encode and store the tile at ``[i, j]`` of the
     ``(B/bb, W/bw, bb, bw)`` cache, and every cell reads it from there.
@@ -110,7 +147,7 @@ def _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
     def encode():
         m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
         return encode_tile(tokens_ref, m, im_ref[j], tie_ref[j], counts_ref,
-                           n=n, alphabet=alphabet, g=g)
+                           n=n, alphabet=alphabet, g=trip)
 
     if not q_cache:
         return encode()
@@ -123,8 +160,8 @@ def _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
     return q_ref[i, j]
 
 
-def _search_tile(acc_ref, o_ref, q, p_ref, j, *, dim: int):
-    """Fold one encoded tile into the Hamming accumulator; flush on last j.
+def _search_tile(acc_ref, q, p_ref, j):
+    """Fold one encoded tile into the Hamming accumulator.
 
     ``p_ref`` is the ``(W/bw, bs, bw)`` prototype slab.  Its rows are
     scored 128 at a time, which bounds the ``(bb, 128, bw)`` XOR
@@ -142,35 +179,52 @@ def _search_tile(acc_ref, o_ref, q, p_ref, j, *, dim: int):
 
     jax.lax.fori_loop(0, bs // sub, rows, 0)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _flush():
-        o_ref[...] = dim - acc_ref[...]
 
-
-def _kernel(tokens_ref, len_ref, im_ref, tie_ref, p_ref, o_ref,
-            counts_ref, acc_ref, *q_cache, n: int, alphabet: int, g: int,
+def _kernel(trips_ref, tokens_ref, len_ref, im_ref, tie_ref, p_ref, ta_ref,
+            o_ref, counts_ref, acc_ref, *q_cache, n: int, alphabet: int,
             dim: int):
     """Automatic-pipeline variant: the ``(W/bw, bs, bw)`` prototype slab
     is a BlockSpec block indexed by the chunk id only, so the pipeline
     fetches it once per chunk and double-buffers the fetch across chunks.
-    ``q_cache`` is the encoded-batch scratch, or empty for one chunk."""
+    ``q_cache`` is the encoded-batch scratch, or empty for one chunk.
+
+    A batch tile with grams to run (``trips_ref[i] > 0``) encodes and
+    searches; one without (every row padding or under ``n`` bases) skips
+    both and, on the last word tile, writes the tie-break vector's score
+    row ``ta_ref`` to each row.
+    """
     k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    trip = trips_ref[i]
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
-                    k, i, j, n=n, alphabet=alphabet, g=g)
-    _search_tile(acc_ref, o_ref, q, p_ref, j, dim=dim)
+    @pl.when(trip > 0)
+    def _live():
+        q = _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref,
+                        q_cache, k, i, j, trip, n=n, alphabet=alphabet)
+        _search_tile(acc_ref, q, p_ref, j)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _flush():
+        @pl.when(trip > 0)
+        def _scored():
+            o_ref[...] = dim - acc_ref[...]
+
+        @pl.when(trip == 0)
+        def _tied():
+            o_ref[...] = jnp.broadcast_to(ta_ref[...], o_ref.shape)
 
 
-def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
-                counts_ref, acc_ref, p_buf, sem, *q_cache,
-                n: int, alphabet: int, g: int, dim: int):
+def _kernel_dma(trips_ref, tokens_ref, len_ref, im_ref, tie_ref, p_hbm,
+                ta_ref, o_ref, counts_ref, acc_ref, p_buf, sem, *q_cache,
+                n: int, alphabet: int, dim: int):
     """Manual double-buffer variant: prototypes stay in HBM and slab
     ``k+1``'s async copy is issued at the FIRST cell of chunk ``k`` —
-    the whole slab's compute window hides the next fetch."""
+    the whole slab's compute window hides the next fetch.  The rotation
+    runs whether or not batch tile 0 has grams to run; the cell's work is
+    :func:`_kernel`'s."""
     k, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     def slab_dma(slot, chunk):
@@ -189,13 +243,9 @@ def _kernel_dma(tokens_ref, len_ref, im_ref, tie_ref, p_hbm, o_ref,
         def _prefetch():
             slab_dma((k + 1) % 2, k + 1).start()
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = _query_tile(tokens_ref, len_ref, im_ref, tie_ref, counts_ref, q_cache,
-                    k, i, j, n=n, alphabet=alphabet, g=g)
-    _search_tile(acc_ref, o_ref, q, p_buf.at[k % 2], j, dim=dim)
+    _kernel(trips_ref, tokens_ref, len_ref, im_ref, tie_ref,
+            p_buf.at[k % 2], ta_ref, o_ref, counts_ref, acc_ref, *q_cache,
+            n=n, alphabet=alphabet, dim=dim)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet", "dim", "bb",
@@ -232,6 +282,11 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         holds a ``B*W*4``-byte encoded-batch cache besides its tile
         buffers, and ``ops.fused_tile_plan`` sizes the limit for both.
 
+    Each batch tile's gram loop runs its reads' own trip count
+    (:func:`tile_trips`), and a tile with none takes the tie-break
+    vector's score row (:func:`tie_agreement`); both are worked out here,
+    on the device, from ``lengths``, ``tie`` and ``p_packed``.
+
     Returns:
       ``(B, S)`` int32 agreement counts in [0, dim] — bit-identical to
       ``am_agreement(hdc_encode(...), p_packed)``.
@@ -251,6 +306,8 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
         double_buffer = not interpret
     wt, nk = w // bw, s // bs
     grid = (nk, b // bb, wt)
+    trips = tile_trips(lengths, bb=bb, n=n, g=g).astype(jnp.int32)
+    tie_row = tie_agreement(tie, p_packed, dim)
 
     # Word-split views: the per-cell word tile becomes a leading-dim
     # dynamic index instead of a lane-dim slice, and the IM / tie /
@@ -261,12 +318,14 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
     tie3 = tie.reshape(wt, 1, bw)
     p4 = p_packed.reshape(nk, bs, wt, bw).transpose(0, 2, 1, 3)
 
+    # Index maps take the scalar-prefetched trip counts last, unused.
     common_specs = [
-        pl.BlockSpec((bb, toks.shape[1]), lambda k, i, j: (i, 0)),
-        pl.BlockSpec((bb, 1), lambda k, i, j: (i, 0)),
-        pl.BlockSpec((wt, n * alphabet, bw), lambda k, i, j: (0, 0, 0)),
-        pl.BlockSpec((wt, 1, bw), lambda k, i, j: (0, 0, 0)),
+        pl.BlockSpec((bb, toks.shape[1]), lambda k, i, j, t: (i, 0)),
+        pl.BlockSpec((bb, 1), lambda k, i, j, t: (i, 0)),
+        pl.BlockSpec((wt, n * alphabet, bw), lambda k, i, j, t: (0, 0, 0)),
+        pl.BlockSpec((wt, 1, bw), lambda k, i, j, t: (0, 0, 0)),
     ]
+    tie_row_spec = pl.BlockSpec((1, bs), lambda k, i, j, t: (0, k))
     scratch = [pltpu.VMEM((WORD_BITS, bb, bw), jnp.int32),
                pltpu.VMEM((bb, bs), jnp.int32)]
     if double_buffer:
@@ -277,19 +336,21 @@ def fused_profile(tokens: jax.Array, lengths: jax.Array,
     else:
         kernel = _kernel
         p_spec = pl.BlockSpec((None, wt, bs, bw),
-                              lambda k, i, j: (k, 0, 0, 0))
+                              lambda k, i, j, t: (k, 0, 0, 0))
     if nk > 1:  # encoded-batch cache: chunk 0 encodes, later chunks reuse
         scratch = scratch + [pltpu.VMEM((b // bb, wt, bb, bw), jnp.uint32)]
 
     return pl.pallas_call(
-        functools.partial(kernel, n=n, alphabet=alphabet, g=g, dim=dim),
-        grid=grid,
-        in_specs=common_specs + [p_spec],
-        out_specs=pl.BlockSpec((bb, bs), lambda k, i, j: (i, k)),
+        functools.partial(kernel, n=n, alphabet=alphabet, dim=dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=common_specs + [p_spec, tie_row_spec],
+            out_specs=pl.BlockSpec((bb, bs), lambda k, i, j, t: (i, k)),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, s), jnp.int32),
-        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(toks, lengths, im3, tie3, p4)
+    )(trips, toks, lengths, im3, tie3, p4, tie_row)

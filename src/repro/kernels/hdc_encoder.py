@@ -71,7 +71,10 @@ def encode_tile(tokens_ref, m, im_rows, tie_row, counts_ref, *, n: int,
       im_rows: ``(n * alphabet, bw)`` uint32 rolled-IM rows of this tile.
       tie_row: ``(1, bw)`` uint32 tie-break words of this tile.
       counts_ref: ``(32, bb, bw)`` int32 scratch for the bit counters.
-      g: grams in an unpadded row (``L - n + 1``, at least 0).
+      g: int32 scalar, the grams the loop runs: at most the row width's
+        ``L - n + 1`` and at least every row's ``m`` — the grams past
+        every row's ``m`` add nothing, so any such bound gives the same
+        words.
 
     Returns:
       ``(bb, bw)`` uint32 packed majority-bundled HD words.
@@ -107,15 +110,16 @@ def encode_tile(tokens_ref, m, im_rows, tie_row, counts_ref, *, n: int,
 
         jax.lax.fori_loop(0, count, body, 0)
 
-    full, rem = divmod(g, LANES)
-    if full:
-        def full_chunk(c, carry):
-            chunk(c, LANES)
-            return carry
+    def full_chunk(c, carry):
+        chunk(c, LANES)
+        return carry
 
-        jax.lax.fori_loop(0, full, full_chunk, 0)
-    if rem:
-        chunk(jnp.int32(full), rem)
+    full, rem = divmod(g, LANES)
+    jax.lax.fori_loop(0, full, full_chunk, 0)
+
+    @pl.when(rem > 0)           # no empty chunk: its token read could
+    def _rem():                 # end past the padded row
+        chunk(full, rem)
 
     # Bundle: majority with tie-break (paper's thresholded counters).
     packed = jnp.zeros((bb, bw), jnp.uint32)
@@ -132,7 +136,8 @@ def _kernel(tokens_ref, len_ref, im_ref, tie_ref, o_ref, counts_ref,
             *, n: int, alphabet: int, g: int):
     m = jnp.maximum(len_ref[...] - (n - 1), 0)   # (bb, 1) valid grams
     o_ref[...] = encode_tile(tokens_ref, m, im_ref[...], tie_ref[...],
-                             counts_ref, n=n, alphabet=alphabet, g=g)
+                             counts_ref, n=n, alphabet=alphabet,
+                             g=jnp.int32(g))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "alphabet", "bb", "bw",

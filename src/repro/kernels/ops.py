@@ -12,6 +12,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import bitops, item_memory
 from repro.core.hd_space import HDSpace
@@ -214,6 +215,24 @@ def fused_tile_plan(b: int, s: int, w: int, *, read_len: int, n: int,
             "vmem_limit_bytes": limit}
 
 
+def gram_steps(lengths: np.ndarray, plan: dict[str, int], *, read_len: int,
+               n: int) -> dict[str, int]:
+    """Row-gram iterations of a fused call's encode, counted on the host.
+
+    ``gram_steps`` is what the kernel runs for these ``(B,)`` lengths:
+    ``bb`` rows times each batch tile's trip count
+    (:func:`repro.kernels.fused_profile.tile_trips`), summed over the
+    ``plan``'s padded batch.  ``gram_steps_shape`` is what it would run
+    with every tile at the static ``L - n + 1``: ``b_pad`` rows of it.
+    """
+    g = max(read_len - n + 1, 0)
+    lens = np.zeros(plan["b_pad"], np.int64)
+    lens[:np.size(lengths)] = np.ravel(lengths)
+    trips = _fused_profile.tile_trips(lens, bb=plan["bb"], n=n, g=g)
+    return {"gram_steps": int(plan["bb"] * trips.sum()),
+            "gram_steps_shape": plan["b_pad"] * g}
+
+
 @functools.partial(jax.jit, static_argnames=("space", "bb", "bw", "bs",
                                              "double_buffer"))
 def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
@@ -236,6 +255,11 @@ def fused_agreement(tokens: jax.Array, lengths: jax.Array, im: jax.Array,
     the plan asks for (:func:`fused_tile_plan`'s ``vmem_limit_bytes``).
     A batch too large for that cache on the chip is split into several
     calls of ``b_call`` rows (:func:`fused_tile_plan`).
+    Each ``bb``-row batch tile runs only the grams its longest read
+    needs, and a tile of padding rows (or reads under ``n`` bases) takes
+    a precomputed tie-break score row without encoding or searching:
+    the kernel works both out from ``lengths`` on the device, and
+    :func:`gram_steps` counts the same grams on the host.
     Bit-identical to
     ``am_agreement(hdc_encode(tokens, lengths, im, tie, space), p, dim)``.
 

@@ -142,6 +142,15 @@ def span(name: str, **args):
     return TraceAnnotation(name, **args)
 
 
+def capturing() -> bool:
+    """Whether a ``jax.profiler`` capture is running, so that a
+    :func:`span` records: for span arguments that cost more to work out
+    than the span itself."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation.is_enabled()
+
+
 #: The ``jax.monitoring`` duration event of one backend compile (or
 #: persistent-cache load) of a jitted program.
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -183,6 +192,6 @@ __all__ = [
     "TraceRecorder", "assemble_trace",
     "NULL_METRICS", "NULL_TRACER",
     "enable_metrics", "enable_tracing", "disable", "metrics", "tracer",
-    "resolve_metrics", "resolve_tracer", "jax_trace", "span",
+    "resolve_metrics", "resolve_tracer", "jax_trace", "span", "capturing",
     "BACKEND_COMPILE_EVENT", "compile_count",
 ]

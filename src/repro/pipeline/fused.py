@@ -39,6 +39,7 @@ from __future__ import annotations
 import warnings
 
 import jax
+import numpy as np
 
 from repro.pipeline.backend import _BackendBase, register_backend
 from repro.pipeline.config import ProfilerConfig
@@ -138,8 +139,8 @@ class PallasFusedBackend(_BackendBase):
             self._autotune = False
         #: (S, L) shape the cached tuning was resolved for
         self._tuned_for: tuple[int, int] | None = None
-        #: kernel_plan's answers, per (B, L, S, tiles)
-        self._plans: dict[tuple, dict[str, int]] = {}
+        #: the kernel's tile plans, per (B, L, S, tiles)
+        self._plans: dict[tuple, dict[str, int | None]] = {}
 
     # -- Backend protocol (standalone kernels; RefDB build + sharded) ------
     def encode(self, tokens: jax.Array, lengths: jax.Array) -> jax.Array:
@@ -173,26 +174,32 @@ class PallasFusedBackend(_BackendBase):
             self._tuned_for = (num_prototypes, read_len)
         return self.tiles
 
-    def kernel_plan(self, batch: int, read_len: int, num_prototypes: int
-                    ) -> dict[str, int]:
-        """``chunks`` and ``encodes`` of the kernel call for this shape.
+    def kernel_plan(self, batch: int, read_len: int, num_prototypes: int,
+                    lengths=None) -> dict[str, int]:
+        """The ``session.dispatch`` span's arguments for this call.
 
-        The ``session.dispatch`` span's arguments: the tile plan's
-        prototype chunks, and encode passes per read (1 with the
-        kernel's encoded-batch cache).  Worked out once per shape.
+        ``chunks``, the tile plan's prototype chunks, and ``encodes``,
+        encode passes per read (1 with the kernel's encoded-batch cache),
+        are worked out once per shape.  Given the call's ``lengths`` as a
+        numpy array, also ``gram_steps`` and ``gram_steps_shape``
+        (:func:`repro.kernels.ops.gram_steps`); a device array is left
+        unread rather than waited for.
         """
+        from repro.kernels import ops
         t = self._resolve_tiles(num_prototypes, read_len)
         key = (batch, read_len, num_prototypes, t["bb"], t["bw"], t["bs"])
         if key not in self._plans:
-            from repro.kernels import ops
-            plan = ops.fused_tile_plan(
+            self._plans[key] = ops.fused_tile_plan(
                 batch, num_prototypes, self.space.num_words,
                 read_len=read_len, n=self.space.ngram,
                 alphabet=self.space.alphabet_size, bb=t["bb"],
                 bw=min(t["bw"], self.space.num_words), bs=t["bs"])
-            self._plans[key] = {"chunks": plan["n_chunks"],
-                                "encodes": plan["encodes"]}
-        return self._plans[key]
+        plan = self._plans[key]
+        out = {"chunks": plan["n_chunks"], "encodes": plan["encodes"]}
+        if isinstance(lengths, np.ndarray):
+            out.update(ops.gram_steps(lengths, plan, read_len=read_len,
+                                      n=self.space.ngram))
+        return out
 
     # -- fused capability (ProfilingSession.classify_batch dispatch) -------
     def tokens_agreement(self, tokens: jax.Array, lengths: jax.Array,

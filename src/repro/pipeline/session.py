@@ -279,8 +279,10 @@ class ProfilingSession:
                 else "tokens_agreement" if fused is not None
                 else "encode_classify")
         kernel_plan = getattr(self.backend, "kernel_plan", None)
+        # The gram-step count reads the lengths: only for a capture.
         plan = {} if kernel_plan is None else kernel_plan(
-            *np.shape(tokens), db.prototypes.shape[0])
+            *np.shape(tokens), db.prototypes.shape[0],
+            lengths if obs.capturing() else None)
         # The span ends once the work is handed to the device: copies in,
         # backend call and tail launched, no result waited for.
         with obs.span("session.dispatch", path=path, **plan):

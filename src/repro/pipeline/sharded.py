@@ -220,11 +220,13 @@ class ShardedBackend:
             out_specs=P(None, None))(q, p, ps)
 
     # -- steps 3+4 fused per shard (only when the base is fused) ----------
-    def _kernel_plan(self, batch: int, read_len: int, num_prototypes: int
-                     ) -> dict[str, int]:
-        """The base kernel's plan on one shard's prototype slice."""
+    def _kernel_plan(self, batch: int, read_len: int, num_prototypes: int,
+                     lengths=None) -> dict[str, int]:
+        """The base kernel's plan on one shard's prototype slice (every
+        shard encodes the whole, replicated batch)."""
         return self.base.kernel_plan(batch, read_len,
-                                     -(-num_prototypes // self.num_shards))
+                                     -(-num_prototypes // self.num_shards),
+                                     lengths)
 
     def _tokens_agreement(self, tokens: jax.Array, lengths: jax.Array,
                           prototypes: jax.Array) -> jax.Array:

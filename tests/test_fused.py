@@ -136,6 +136,75 @@ def test_fused_encode_cache_matches_reference(dim, ngram, b, length, s,
         _reference_agreement(space, toks, lens, protos))
 
 
+def _tile_lengths(mix, ngram, length, rng):
+    """Lengths of four 8-row batch tiles, by mix."""
+    def live(k=8):
+        return rng.integers(ngram, length + 1, k)
+
+    pad = np.zeros(8, int)
+    short = rng.integers(1, ngram, 8)                    # under the n-gram
+    if mix == "padding_around_live":      # padding before and after live
+        tiles = [pad, live(), pad, np.r_[live(5), 0, 0, 0]]
+    elif mix == "under_ngram":            # live rows with no valid gram
+        tiles = [short, np.r_[short[:4], 0, 0, 0, 0], live(), pad]
+    elif mix == "one_long":               # one long read among short ones
+        tiles = [np.r_[rng.integers(ngram, ngram + 4, 7), length]
+                 for _ in range(4)]
+    else:                                 # every row at the full length
+        tiles = [np.full(8, length)] * 4
+    return np.concatenate(tiles).astype(np.int32)
+
+
+@pytest.mark.parametrize("double_buffer", [False, True],
+                         ids=["pipeline", "dma"])
+@pytest.mark.parametrize("s,tiles", [(100, {}), (256, {"bs": 128})],
+                         ids=["one_chunk", "cached"])
+@pytest.mark.parametrize("mix", ["padding_around_live", "under_ngram",
+                                 "one_long", "full"])
+def test_fused_length_bounded_tiles_match_reference(mix, s, tiles,
+                                                    double_buffer):
+    """Each batch tile runs its longest read's grams, and a tile with
+    none takes the tie-break row: bit-exact on every row, padding rows
+    included, on a one-chunk call and a cached multi-chunk call."""
+    space = HDSpace(dim=512, ngram=5, z_threshold=3.0)
+    rng = np.random.default_rng(17)
+    lens = _tile_lengths(mix, 5, 60, rng)
+    toks = rng.integers(0, 4, (32, 60)).astype(np.int32)
+    protos = rng.integers(0, 2 ** 32, (s, space.num_words), dtype=np.uint32)
+    np.testing.assert_array_equal(
+        _fused_agreement(space, toks, lens, protos,
+                         double_buffer=double_buffer, **tiles),
+        _reference_agreement(space, toks, lens, protos))
+
+
+def test_tile_trips_and_gram_steps():
+    """A tile's trip count is ``max(0, max(len) - n + 1)``, on the device
+    as on the host, and the host's gram steps sum ``bb`` rows of it."""
+    import jax.numpy as jnp
+
+    from repro.kernels import fused_profile
+
+    n, length, bb = 5, 60, 8
+    rng = np.random.default_rng(3)
+    lens = np.concatenate([_tile_lengths(mix, n, length, rng) for mix in (
+        "padding_around_live", "under_ngram", "one_long", "full")])
+    want = [max(0, int(lens[t:t + bb].max()) - n + 1)
+            for t in range(0, len(lens), bb)]
+    assert want[:4] == [0, want[1], 0, want[3]] and want[4:6] == [0, 0]
+    assert want[-4:] == [length - n + 1] * 4
+    g = length - n + 1
+    np.testing.assert_array_equal(
+        fused_profile.tile_trips(lens, bb=bb, n=n, g=g), want)
+    np.testing.assert_array_equal(fused_profile.tile_trips(
+        jnp.asarray(lens)[:, None], bb=bb, n=n, g=g), want)
+    plan = ops.fused_tile_plan(len(lens) - 3, 100, 16, read_len=length, n=n)
+    assert plan["b_pad"] == len(lens)
+    # The last three rows (full length) are left off: the padded
+    # batch's last tile still runs the full count for its five rows.
+    assert ops.gram_steps(lens[:-3], plan, read_len=length, n=n) == {
+        "gram_steps": bb * sum(want), "gram_steps_shape": len(lens) * g}
+
+
 def test_fused_tile_plan_sizes_the_encode_cache():
     """The cache holds the padded batch's encoded words when the call has
     two chunks or more, and nothing with one; the tile buffers (and so
@@ -197,7 +266,11 @@ def test_fused_batch_split_when_cache_exceeds_vmem(monkeypatch):
 
 def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample):
     """``session.dispatch`` carries the kernel plan of the call: one
-    encode per read, on one chunk and on several."""
+    encode per read, and, while a capture runs, the gram steps its tiles
+    run against the padded shape's, on one chunk and on several; under
+    ``sharded``, one shard's."""
+    import jax.numpy as jnp
+
     from repro import obs
 
     seen = []
@@ -208,26 +281,43 @@ def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample):
         return real_span(name, **args)
 
     monkeypatch.setattr(obs, "span", recording_span)
+    # Tile 0: eight 100-bp reads, 96 grams each; tile 1: padding.
+    lens = np.r_[[100] * 8, [0] * 8].astype(np.int32)
+    steps = {"gram_steps": 8 * 96, "gram_steps_shape": 16 * (150 - 4)}
     for window, bs, chunks in [(1024, 4096, 1), (128, 128, 2)]:
         s = ProfilingSession(_config(window=window,
                                      backend_options={"bs": bs}))
         s.build_refdb(sample.genomes)
-        seen.clear()
-        s.classify_batch(sample.tokens[:16], sample.lengths[:16])
-        (args,) = [a for n, a in seen if n == "session.dispatch"]
-        assert args == {"path": "tokens_agreement", "chunks": chunks,
-                        "encodes": 1}
+        for capture in (False, True):
+            monkeypatch.setattr(obs, "capturing", lambda c=capture: c)
+            seen.clear()
+            s.classify_batch(sample.tokens[:16], lens)
+            (args,) = [a for n, a in seen if n == "session.dispatch"]
+            # With no capture the span records nothing: no steps counted.
+            assert args == {"path": "tokens_agreement", "chunks": chunks,
+                            "encodes": 1, **(steps if capture else {})}
+        num_prototypes = s.refdb.prototypes.shape[0]
         assert s.backend.kernel_plan(
             16, sample.tokens.shape[1],
-            s.refdb.prototypes.shape[0]) == {"chunks": chunks, "encodes": 1}
-    # sharded over the fused kernel: the plan of one shard's slice
+            num_prototypes) == {"chunks": chunks, "encodes": 1}
+        # Lengths still on the device are not waited for.
+        assert s.backend.kernel_plan(
+            16, sample.tokens.shape[1], num_prototypes,
+            jnp.asarray(lens)) == {"chunks": chunks, "encodes": 1}
+    # sharded over the fused kernel: the plan of one shard's slice, which
+    # encodes the whole batch
     sharded = ProfilingSession(_config(
         backend="sharded", backend_options={"base": "pallas_fused",
                                             "bs": 128})).backend
     per_shard = 129 * sharded.num_shards                # 2 chunks a shard
+    lens = np.r_[[40] * 3, [0] * 13].astype(np.int32)
     assert sharded.kernel_plan(16, 40, per_shard) \
         == sharded.base.kernel_plan(16, 40, 129) == {"chunks": 2,
                                                      "encodes": 1}
+    assert sharded.kernel_plan(16, 40, per_shard, lens) \
+        == sharded.base.kernel_plan(16, 40, 129, lens) == {
+            "chunks": 2, "encodes": 1, "gram_steps": 8 * 36,
+            "gram_steps_shape": 16 * 36}
     unfused = ProfilingSession(_config(
         backend="sharded", backend_options={"base": "reference"})).backend
     assert getattr(unfused, "kernel_plan", None) is None

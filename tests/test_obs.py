@@ -503,11 +503,14 @@ def test_profiler_leaves_results_bit_identical(traced_service, sample,
 def test_spans_record_nothing_without_a_profiler(tmp_path):
     import jax
 
+    assert not obs.capturing()
     with obs.span("serve.step", rows=3) as s:
         s.set_metadata(tokens=5)
     with obs.jax_trace(tmp_path):
+        assert obs.capturing()
         with obs.span("serve.other", rows=4):
             pass
+    assert not obs.capturing()
     assert [(s[1], s[4]) for s in _host_spans(tmp_path)] \
         == [("serve.other", {"rows": 4})]
     before = obs.compile_count()
