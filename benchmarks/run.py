@@ -17,7 +17,6 @@ Prints ``name,us_per_call,derived`` CSV (see benchmarks.common.emit).
   shard_scaling  §scale   sharded-AM reads/s + RefDB bytes/device vs shards
                           (grow the sweep with
                           XLA_FLAGS=--xla_force_host_platform_device_count=N)
-  roofline    §Roofline three-term analysis from dry-run artifacts
 
 ``--smoke`` switches to the CI benchmark smoke instead: a tiny sample
 through every hot-path backend, emitting machine-readable
@@ -30,8 +29,8 @@ from __future__ import annotations
 import sys
 
 from benchmarks import (accel_sim, accuracy, acc_perf, build_time, common,
-                        energy, fleet_serve, memory, query_perf, roofline,
-                        serve_perf, shard_scaling, tenant_serve)
+                        energy, fleet_serve, memory, query_perf, serve_perf,
+                        shard_scaling, tenant_serve)
 
 
 def main() -> None:
@@ -69,8 +68,6 @@ def main() -> None:
         fleet_serve.run(community)
     if want("shard_scaling"):
         shard_scaling.run(community)
-    if want("roofline"):
-        roofline.run()
 
 
 if __name__ == "__main__":

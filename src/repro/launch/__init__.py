@@ -1,1 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve/profile drivers."""
+"""Launchers: the profiler's drivers (``profile_run``, ``serve_profiler``,
+``serve_fleet``) and the persistent compile cache they share."""

@@ -35,8 +35,8 @@ Registered backends:
                    read model; Khan et al., see PAPERS.md).
   sharded          prototype-axis model parallelism over a device mesh,
                    wrapping any of the above as its ``base``
-                   (:mod:`repro.pipeline.sharded`, built on
-                   ``repro.distributed.sharding``).
+                   (:mod:`repro.pipeline.sharded`, on the 1-D mesh of
+                   its own ``make_profile_mesh``).
 
 All are bit-exact twins at default options (enforced by
 ``tests/test_pipeline.py`` and, across mesh sizes, by

@@ -3,8 +3,8 @@
 One config names everything a run depends on — the HD space (step 1), the
 RefDB windowing (step 2), the batch shape of the streamed query path
 (steps 3-4), and the *backend* that executes encode/agreement.  It is a
-frozen dataclass in the style of :class:`repro.config.ModelConfig`:
-hashable (usable as a jit static argument) and JSON round-trippable.
+frozen dataclass: hashable (usable as a jit static argument) and JSON
+round-trippable.
 :meth:`~ProfilerConfig.fingerprint` covers every field (the config's
 identity); :meth:`~ProfilerConfig.refdb_fingerprint` covers exactly the
 fields that determine RefDB content, so two configs that could produce

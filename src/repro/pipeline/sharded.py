@@ -6,7 +6,7 @@ single accelerator it is capped by that device's memory and FLOPs.
 In-memory HDC hardware scales the same search by splitting the
 associative memory across crossbar arrays — this backend is the digital
 analogue: the *prototype* axis is partitioned across a 1-D ``('shard',)``
-device mesh (``repro.distributed.sharding.PROFILE_RULES``), every shard
+device mesh (:func:`make_profile_mesh`), every shard
 scores the (replicated, cheap) query batch against its local slice of
 prototypes with **any base backend's** ``agreement``, and per-shard
 partial species scores merge with an elementwise ``pmax`` — exact, so the
@@ -47,11 +47,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import assoc_memory
 from repro.core.assoc_memory import RefDB
-from repro.distributed import sharding
 from repro.core.bitops import pad_to_multiple
 from repro.pipeline.backend import register_backend, resolve_backend
 from repro.pipeline.config import ProfilerConfig
@@ -100,11 +100,26 @@ def pad_refdb(db: RefDB, multiple: int) -> RefDB:
     )
 
 
+def make_profile_mesh(num_shards: int | None = None) -> Mesh:
+    """1-D ``('shard',)`` mesh over the first ``num_shards`` local devices.
+
+    Prototype-axis model parallelism only: reads are cheap to replicate,
+    the AM is not.  ``None`` takes every local device.
+    """
+    devices = jax.devices()
+    n = len(devices) if num_shards is None else num_shards
+    if not 1 <= n <= len(devices):
+        raise ValueError(
+            f"num_shards must be in [1, {len(devices)}] (local devices), "
+            f"got {n}")
+    return Mesh(np.asarray(devices[:n]), ("shard",))
+
+
 def placement_shardings(mesh) -> tuple[NamedSharding, NamedSharding]:
-    """(prototype, proto_species) shardings under PROFILE_RULES."""
-    with sharding.use_rules(mesh, sharding.PROFILE_RULES):
-        return (sharding.sharding_for(("protos", "hd_words")),
-                sharding.sharding_for(("protos",)))
+    """(prototype, proto_species) shardings: rows split over ``'shard'``,
+    the packed HD words contiguous within a shard."""
+    return (NamedSharding(mesh, P("shard", None)),
+            NamedSharding(mesh, P("shard")))
 
 
 def place_refdb(db: RefDB, mesh) -> RefDB:
@@ -150,7 +165,7 @@ class ShardedBackend:
         self.config = config
         self.base = resolve_backend(base_name, base_config)
         self.space = base_config.space
-        self.mesh = sharding.make_profile_mesh(shards or None)
+        self.mesh = make_profile_mesh(shards or None)
         self.num_shards = self.mesh.size
         self._agreement = jax.jit(self._agreement_impl)
         self._scores = jax.jit(self._scores_impl,

@@ -1,4 +1,4 @@
-"""Serving layer: single-DB service, multi-tenant control plane, legacy LM.
+"""Serving layer: single-DB service and multi-tenant control plane.
 
 :class:`ProfilingService` (:mod:`repro.serve.profiler_service`) is the
 data plane — many concurrent requests over one RefDB, bit-exact with
@@ -11,9 +11,7 @@ quotas and zero-downtime hot-swap.  :mod:`repro.serve.fleet` replicates
 that whole stack across simulated hosts behind one
 :class:`FleetController` — pull-based version replication, load-aware
 tenant routing with mid-flight failover, and fleet-coordinated
-two-phase hot-swaps.  The LM prefill/decode modules
-(:mod:`repro.serve.serve_step`, :mod:`repro.serve.batching`) are the
-seed repo's stack, kept working as legacy entry points.
+two-phase hot-swaps.
 """
 
 from repro.serve.scheduler import Cohort, FixedShapeScheduler, pow2_buckets

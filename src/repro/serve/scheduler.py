@@ -1,23 +1,19 @@
 """Generic fixed-shape cohort scheduler: the admission core of serving.
 
-Every serving path in this repo — the legacy LM decode loop
-(:class:`repro.serve.batching.CohortScheduler`) and the profiler service
-(:class:`repro.serve.profiler_service.ProfilingService`) — has the same
-shape problem: jit compiles one executable per input shape, so admission
-must quantize work into a *small, bounded* set of shapes.  This module
-owns that policy once:
+The profiler service (:class:`repro.serve.profiler_service.ProfilingService`)
+has a shape problem: jit compiles one executable per input shape, so
+admission must quantize work into a *small, bounded* set of shapes.  This
+module owns that policy:
 
-  * items are submitted FIFO with a ``size`` (prompt length, read length);
+  * items are submitted FIFO with a ``size`` (a read's length);
   * :meth:`FixedShapeScheduler.next_cohort` pops up to ``slots`` items and
     pads their variable dimension up to a *bucket* — the smallest
     configured padding length holding the cohort's largest item — so the
-    jit cache sees at most ``len(buckets)`` shapes per slot count;
-  * ``buckets=None`` degrades to exact-max padding (the legacy LM
-    behavior: one shape per distinct cohort max).
+    jit cache sees at most ``len(buckets)`` shapes per slot count.
 
 The scheduler is deliberately compute-free: it never touches arrays, only
 decides *who* runs together and *at what padded length*.  Callers own the
-actual padding (left-pad prompts, right-pad reads) and the step function.
+actual padding (right-pad reads) and the step function.
 """
 
 from __future__ import annotations
@@ -58,26 +54,22 @@ class Cohort(Generic[T]):
 class FixedShapeScheduler(Generic[T]):
     """FIFO admission into padding-bucketed, bounded-shape cohorts."""
 
-    def __init__(self, *, slots: int, buckets: Sequence[int] | None = None):
+    def __init__(self, *, slots: int, buckets: Sequence[int]):
         """Args:
           slots: maximum items per cohort (the fixed batch dimension).
-          buckets: allowed padding lengths, ascending; an item longer than
-            ``max(buckets)`` is rejected at submit.  ``None`` pads each
-            cohort to its exact max size (unbounded shape set — only for
-            callers that control sizes themselves).
+          buckets: allowed padding lengths; an item longer than
+            ``max(buckets)`` is rejected at submit.
         """
         if slots < 1:
             raise ValueError("slots must be >= 1")
         self.slots = slots
-        self.buckets = tuple(sorted(buckets)) if buckets is not None else None
-        if self.buckets is not None and not self.buckets:
-            raise ValueError("buckets must be non-empty (or None)")
+        self.buckets = tuple(sorted(buckets))
+        if not self.buckets:
+            raise ValueError("buckets must be non-empty")
         self._queue: deque[tuple[T, int]] = deque()
 
     def bucket_for(self, size: int) -> int:
         """Smallest configured padding length >= ``size``."""
-        if self.buckets is None:
-            return size
         for b in self.buckets:
             if size <= b:
                 return b

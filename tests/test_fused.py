@@ -205,33 +205,123 @@ def test_tile_trips_and_gram_steps():
         "gram_steps": bb * sum(want), "gram_steps_shape": len(lens) * g}
 
 
-def test_fused_tile_plan_sizes_the_encode_cache():
+def _cell_lengths(pattern, bucket, rows, rng):
+    """``rows`` read lengths in a ``bucket``, by pattern."""
+    full = 150 if bucket == 256 else bucket
+    if pattern == "full":
+        return np.full(rows, full)
+    if pattern == "padding":
+        return np.zeros(rows, int)
+    if pattern == "ragged":               # log-normal, as nanopore reads
+        return rng.lognormal(np.log(0.6 * bucket), 0.5, rows).clip(
+            0, bucket).astype(int)
+    if pattern == "one_long":             # one read per tile at the bucket
+        lens = rng.integers(CELL_N, CELL_N + 4, rows)
+        lens[rng.integers(0, 8)::8] = bucket
+        return lens
+    if pattern == "under_n":              # no read has a valid gram
+        return rng.integers(0, CELL_N, rows)
+    return np.full(rows - 3, full)        # tail: last tile part padding
+
+
+@pytest.mark.parametrize("bucket,rows", [(256, 4096), (4096, 256)],
+                         ids=["short", "ont"])
+@pytest.mark.parametrize("pattern", ["full", "padding", "ragged",
+                                     "one_long", "under_n", "tail"])
+def test_gram_steps_count_the_kernels_tile_trips(pattern, bucket, rows):
+    """The host's gram count is ``bb`` rows of the trip counts the kernel
+    computes on the device for the same padded lengths, and each tile's
+    trip count is its longest read's grams, capped at the bucket's."""
+    import jax.numpy as jnp
+
+    from repro.kernels import fused_profile
+
+    lens = _cell_lengths(pattern, bucket, rows, np.random.default_rng(7))
+    plan = ops.fused_tile_plan(len(lens), 1480, CELL_W, read_len=bucket,
+                               n=CELL_N)
+    bb, g = plan["bb"], bucket - CELL_N + 1
+    padded = np.zeros(plan["b_pad"], np.int32)
+    padded[:len(lens)] = lens
+    device = np.asarray(fused_profile.tile_trips(
+        jnp.asarray(padded)[:, None], bb=bb, n=CELL_N, g=g))
+    brute = [min(g, max(0, int(padded[t:t + bb].max()) - CELL_N + 1))
+             for t in range(0, len(padded), bb)]
+    np.testing.assert_array_equal(device, brute)
+    assert ops.gram_steps(lens, plan, read_len=bucket, n=CELL_N) == {
+        "gram_steps": bb * int(device.sum()),
+        "gram_steps_shape": plan["b_pad"] * g}
+
+
+@pytest.mark.parametrize("b,s,chunks", [
+    (4096, 1480, 2), (256, 1480, 2), (4093, 700, 1)],
+    ids=["cohort_4096", "cohort_256", "one_chunk"])
+def test_fused_tile_plan_sizes_the_encode_cache(b, s, chunks):
     """The cache holds the padded batch's encoded words when the call has
     two chunks or more, and nothing with one; the tile buffers (and so
     ``bs`` and the chunk count) are what they were without it."""
     w, n, read_len = 1280, 16, 256                    # D=40960, AFS20 short
     cost = dict(read_len=read_len, n=n)
-    for b, s, chunks in [(4096, 1480, 2), (256, 1480, 2), (4093, 700, 1)]:
-        plan = ops.fused_tile_plan(b, s, w, **cost)
-        assert plan["n_chunks"] == chunks
-        assert plan["bs"] == 768
-        tiles = ops.vmem_bytes(plan, **cost)
-        assert tiles <= ops.VMEM_BUDGET
-        assert plan["n_calls"] == 1 and plan["b_call"] == plan["b_pad"]
-        assert plan["encodes"] == 1
-        if chunks == 1:
-            assert plan["cache_bytes"] == 0
-            assert plan["vmem_limit_bytes"] is None
-        else:
-            assert plan["cache_bytes"] == plan["b_pad"] * plan["w_pad"] * 4
-    short = ops.fused_tile_plan(4096, 1480, w, **cost)
-    held = ops.vmem_bytes(short, **cost) + short["cache_bytes"]
-    assert held > ops.VMEM_BUDGET                     # 20 MiB of cache
-    assert held + ops.VMEM_HEADROOM <= short["vmem_limit_bytes"] \
-        <= ops.vmem_capacity() - ops.VMEM_HEADROOM
-    ont = ops.fused_tile_plan(256, 1480, w, read_len=4096, n=n)
-    assert ops.vmem_bytes(ont, read_len=4096, n=n) + ont["cache_bytes"] \
-        <= ops.VMEM_BUDGET and ont["vmem_limit_bytes"] is None
+    plan = ops.fused_tile_plan(b, s, w, **cost)
+    assert plan["n_chunks"] == chunks
+    assert plan["bs"] == 768
+    tiles = ops.vmem_bytes(plan, **cost)
+    assert tiles <= ops.VMEM_BUDGET
+    assert plan["n_calls"] == 1 and plan["b_call"] == plan["b_pad"]
+    assert plan["encodes"] == 1
+    if chunks == 1:
+        assert plan["cache_bytes"] == 0
+        assert plan["vmem_limit_bytes"] is None
+    else:
+        assert plan["cache_bytes"] == plan["b_pad"] * plan["w_pad"] * 4
+    if b == 4096:               # 20 MiB of cache raises the scoped limit
+        held = tiles + plan["cache_bytes"]
+        assert held > ops.VMEM_BUDGET
+        assert held + ops.VMEM_HEADROOM <= plan["vmem_limit_bytes"] \
+            <= ops.vmem_capacity() - ops.VMEM_HEADROOM
+    elif b == 256:              # the ONT cohort at its 4,096-bp bucket
+        ont = ops.fused_tile_plan(b, s, w, read_len=4096, n=n)
+        assert ops.vmem_bytes(ont, read_len=4096, n=n) + ont["cache_bytes"] \
+            <= ops.VMEM_BUDGET and ont["vmem_limit_bytes"] is None
+
+
+#: The cells' widths: D=40960 (W=1280 words), 16-mers.
+CELL_W, CELL_N = 1280, 16
+
+#: Plans the cells run: the short-read cohort (4,096 x 256) and the ONT
+#: cohort (256 x 4,096), against the AFS20 database's 1,480 prototypes.
+CELL_PLANS = {
+    (4096, 1480, 256): {"bs": 768, "n_chunks": 2, "s_pad": 1536,
+                        "cache_bytes": 20_971_520,
+                        "vmem_limit_bytes": 35_651_584},
+    (256, 1480, 4096): {"bs": 768, "n_chunks": 2, "s_pad": 1536,
+                        "cache_bytes": 1_310_720, "vmem_limit_bytes": None},
+}
+
+
+@pytest.mark.parametrize("read_len", [256, 4096])
+@pytest.mark.parametrize("s", [1, 130, 1480, 14800])
+@pytest.mark.parametrize("b", [1, 5, 256, 4093, 4096])
+def test_fused_tile_plan_holds_at_cell_widths(b, s, read_len):
+    """At the cells' widths every plan pads S once to whole 128-row
+    chunks, pads B to whole calls of whole tiles, holds a cache exactly
+    when it has two chunks, and keeps its buffers within the budget and,
+    with the cache, within the ceiling, raising the limit only past the
+    budget."""
+    cost = dict(read_len=read_len, n=CELL_N)
+    plan = ops.fused_tile_plan(b, s, CELL_W, **cost)
+    bs = plan["bs"]
+    assert s <= plan["s_pad"] < s + bs
+    assert plan["s_pad"] == plan["n_chunks"] * bs and bs % 128 == 0
+    assert b <= plan["b_pad"] == plan["n_calls"] * plan["b_call"]
+    assert plan["b_call"] % max(plan["bb"], 8) == 0
+    assert (plan["cache_bytes"] == 0) == (plan["n_chunks"] == 1)
+    tiles = ops.vmem_bytes(plan, **cost)
+    assert tiles <= ops.VMEM_BUDGET
+    held = tiles + plan["cache_bytes"]
+    assert held <= ops.vmem_ceiling()
+    assert (plan["vmem_limit_bytes"] is None) == (held <= ops.VMEM_BUDGET)
+    for key, want in CELL_PLANS.get((b, s, read_len), {}).items():
+        assert plan[key] == want, key
 
 
 def test_fused_batch_split_when_cache_exceeds_vmem(monkeypatch):
@@ -264,13 +354,37 @@ def test_fused_batch_split_when_cache_exceeds_vmem(monkeypatch):
         got, _reference_agreement(space, toks, lens, protos))
 
 
-def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample):
+@pytest.fixture(scope="module")
+def fused_sessions(sample):
+    """One fused session per window, built on first use: window 1024 puts
+    the database in one 4,096-row chunk, window 128 in two 128-row ones."""
+    sessions = {}
+
+    def get(window):
+        if window not in sessions:
+            bs = 4096 if window == 1024 else 128
+            s = ProfilingSession(_config(window=window,
+                                         backend_options={"bs": bs}))
+            s.build_refdb(sample.genomes)
+            sessions[window] = s
+        return sessions[window]
+    return get
+
+
+#: Tile 0: eight 100-bp reads, 96 grams each; tile 1: padding.
+DISPATCH_LENS = np.r_[[100] * 8, [0] * 8].astype(np.int32)
+
+
+@pytest.mark.parametrize("capture", [False, True],
+                         ids=["no_capture", "capture"])
+@pytest.mark.parametrize("window,chunks", [(1024, 1), (128, 2)],
+                         ids=["one_chunk", "two_chunks"])
+def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample,
+                                                fused_sessions, window,
+                                                chunks, capture):
     """``session.dispatch`` carries the kernel plan of the call: one
     encode per read, and, while a capture runs, the gram steps its tiles
-    run against the padded shape's, on one chunk and on several; under
-    ``sharded``, one shard's."""
-    import jax.numpy as jnp
-
+    run against the padded shape's, on one chunk and on several."""
     from repro import obs
 
     seen = []
@@ -281,31 +395,37 @@ def test_dispatch_span_names_chunks_and_encodes(monkeypatch, sample):
         return real_span(name, **args)
 
     monkeypatch.setattr(obs, "span", recording_span)
-    # Tile 0: eight 100-bp reads, 96 grams each; tile 1: padding.
-    lens = np.r_[[100] * 8, [0] * 8].astype(np.int32)
+    monkeypatch.setattr(obs, "capturing", lambda: capture)
     steps = {"gram_steps": 8 * 96, "gram_steps_shape": 16 * (150 - 4)}
-    for window, bs, chunks in [(1024, 4096, 1), (128, 128, 2)]:
-        s = ProfilingSession(_config(window=window,
-                                     backend_options={"bs": bs}))
-        s.build_refdb(sample.genomes)
-        for capture in (False, True):
-            monkeypatch.setattr(obs, "capturing", lambda c=capture: c)
-            seen.clear()
-            s.classify_batch(sample.tokens[:16], lens)
-            (args,) = [a for n, a in seen if n == "session.dispatch"]
-            # With no capture the span records nothing: no steps counted.
-            assert args == {"path": "tokens_agreement", "chunks": chunks,
-                            "encodes": 1, **(steps if capture else {})}
-        num_prototypes = s.refdb.prototypes.shape[0]
-        assert s.backend.kernel_plan(
-            16, sample.tokens.shape[1],
-            num_prototypes) == {"chunks": chunks, "encodes": 1}
-        # Lengths still on the device are not waited for.
-        assert s.backend.kernel_plan(
-            16, sample.tokens.shape[1], num_prototypes,
-            jnp.asarray(lens)) == {"chunks": chunks, "encodes": 1}
-    # sharded over the fused kernel: the plan of one shard's slice, which
-    # encodes the whole batch
+    fused_sessions(window).classify_batch(sample.tokens[:16], DISPATCH_LENS)
+    (args,) = [a for n, a in seen if n == "session.dispatch"]
+    # With no capture the span records nothing: no steps counted.
+    assert args == {"path": "tokens_agreement", "chunks": chunks,
+                    "encodes": 1, **(steps if capture else {})}
+
+
+@pytest.mark.parametrize("window,chunks", [(1024, 1), (128, 2)],
+                         ids=["one_chunk", "two_chunks"])
+def test_kernel_plan_names_chunks_and_encodes(sample, fused_sessions,
+                                              window, chunks):
+    """The backend's plan without lengths, and with lengths still on the
+    device (not waited for), names only the chunks and the encodes."""
+    import jax.numpy as jnp
+
+    s = fused_sessions(window)
+    num_prototypes = s.refdb.prototypes.shape[0]
+    read_len = sample.tokens.shape[1]
+    assert s.backend.kernel_plan(16, read_len, num_prototypes) \
+        == {"chunks": chunks, "encodes": 1}
+    assert s.backend.kernel_plan(16, read_len, num_prototypes,
+                                 jnp.asarray(DISPATCH_LENS)) \
+        == {"chunks": chunks, "encodes": 1}
+
+
+def test_sharded_kernel_plan_is_one_shards_slice():
+    """Under ``sharded`` over the fused kernel the plan is that of one
+    shard's prototype slice, which encodes the whole batch; over an
+    unfused base there is no plan."""
     sharded = ProfilingSession(_config(
         backend="sharded", backend_options={"base": "pallas_fused",
                                             "bs": 128})).backend

@@ -17,6 +17,7 @@ from repro.pipeline import (ArraySource, FastqSource, IterableSource,
 
 SP = HDSpace(dim=512, ngram=5, z_threshold=3.0)
 SPEC = synth.CommunitySpec(num_species=4, genome_len=6_000, seed=11)
+BACKENDS = available_backends()
 
 
 def _config(**kw):
@@ -29,6 +30,13 @@ def _config(**kw):
 @pytest.fixture(scope="module")
 def sample():
     return SyntheticSource(SPEC, num_reads=96, present=[0, 2])
+
+
+@pytest.fixture(scope="module")
+def reference_report(sample):
+    s = ProfilingSession(_config())
+    s.build_refdb(sample.genomes)
+    return s.profile(sample)
 
 
 # -- backend registry ------------------------------------------------------
@@ -44,7 +52,8 @@ def test_unknown_backend_rejected():
         ProfilingSession(_config(backend="no_such_backend"))
 
 
-def test_backend_parity_encode_and_agreement(sample):
+@pytest.mark.parametrize("name", BACKENDS)
+def test_backend_parity_encode_and_agreement(name):
     """Every registered backend matches the reference bit-exactly."""
     rng = np.random.default_rng(0)
     toks = rng.integers(0, 4, (16, 60)).astype(np.int32)
@@ -53,28 +62,23 @@ def test_backend_parity_encode_and_agreement(sample):
     q_ref = np.asarray(ref.encode(toks, lens))
     protos = q_ref[:7]  # any packed (S, W) array works as prototypes
     a_ref = np.asarray(ref.agreement(q_ref, protos))
-    for name in available_backends():
-        be = resolve_backend(name, _config(backend=name))
-        np.testing.assert_array_equal(
-            np.asarray(be.encode(toks, lens)), q_ref, err_msg=name)
-        np.testing.assert_array_equal(
-            np.asarray(be.agreement(q_ref, protos)), a_ref, err_msg=name)
+    be = resolve_backend(name, _config(backend=name))
+    np.testing.assert_array_equal(np.asarray(be.encode(toks, lens)), q_ref)
+    np.testing.assert_array_equal(
+        np.asarray(be.agreement(q_ref, protos)), a_ref)
 
 
-def test_profile_report_is_backend_invariant(sample):
-    """Swapping the backend changes no ProfileReport field (acceptance)."""
-    reports = {}
-    for name in available_backends():
-        s = ProfilingSession(_config(backend=name))
-        s.build_refdb(sample.genomes)
-        reports[name] = s.profile(sample)
-    ref = reports["reference"]
-    for name, rep in reports.items():
-        for f in dataclasses.fields(rep):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(rep, f.name)),
-                np.asarray(getattr(ref, f.name)),
-                err_msg=f"{name}.{f.name}")
+@pytest.mark.parametrize("name", BACKENDS)
+def test_profile_report_is_backend_invariant(sample, reference_report, name):
+    """Swapping the backend changes no ProfileReport field (acceptance);
+    a fresh ``reference`` session reproduces its own report."""
+    s = ProfilingSession(_config(backend=name))
+    s.build_refdb(sample.genomes)
+    rep = s.profile(sample)
+    for f in dataclasses.fields(rep):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(rep, f.name)),
+            np.asarray(getattr(reference_report, f.name)), err_msg=f.name)
 
 
 # -- ProfilerConfig --------------------------------------------------------
@@ -348,25 +352,24 @@ def test_profile_report_json_roundtrip(sample):
 
 # -- ProfilingSession ------------------------------------------------------
 
-def test_classify_batch_matches_profile_on_every_backend(sample):
+@pytest.mark.parametrize("name", BACKENDS)
+def test_classify_batch_matches_profile_on_every_backend(sample, name):
     """The step-level primitive IS the profile() hot path: driving it by
     hand reproduces profile()'s accumulator inputs bit-exactly, for every
     registered backend."""
     from repro.pipeline import ProfileAccumulator
-    for name in available_backends():
-        s = ProfilingSession(_config(backend=name))
-        db = s.build_refdb(sample.genomes)
-        acc = ProfileAccumulator(db.num_species)
-        for i, b in enumerate(sample.batches(s.config.batch_size)):
-            res = s.classify_batch(b.tokens, b.lengths,
-                                   num_valid=b.num_valid, index=i)
-            assert res.index == i and res.num_valid == b.num_valid
-            n = res.num_valid
-            acc.add(np.asarray(res.classification.hits)[:n],
-                    np.asarray(res.classification.category)[:n])
-        manual = acc.finalize(np.asarray(db.genome_lengths),
-                              db.species_names)
-        assert manual.to_json() == s.profile(sample).to_json(), name
+    s = ProfilingSession(_config(backend=name))
+    db = s.build_refdb(sample.genomes)
+    acc = ProfileAccumulator(db.num_species)
+    for i, b in enumerate(sample.batches(s.config.batch_size)):
+        res = s.classify_batch(b.tokens, b.lengths,
+                               num_valid=b.num_valid, index=i)
+        assert res.index == i and res.num_valid == b.num_valid
+        n = res.num_valid
+        acc.add(np.asarray(res.classification.hits)[:n],
+                np.asarray(res.classification.category)[:n])
+    manual = acc.finalize(np.asarray(db.genome_lengths), db.species_names)
+    assert manual.to_json() == s.profile(sample).to_json()
 
 
 def test_session_requires_refdb(sample):

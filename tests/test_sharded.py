@@ -20,7 +20,7 @@ from repro.genomics import synth
 from repro.pipeline import (ProfilerConfig, ProfilingSession, SyntheticSource,
                             available_backends, pad_refdb, per_device_bytes,
                             place_refdb, resolve_backend)
-from repro.distributed import sharding
+from repro.pipeline.sharded import make_profile_mesh
 
 SP = HDSpace(dim=512, ngram=5, z_threshold=3.0)
 SPEC = synth.CommunitySpec(num_species=4, genome_len=6_000, seed=11)
@@ -115,7 +115,7 @@ def test_pad_refdb_tags_padding_out_of_range(reference):
 
 def test_place_refdb_preserves_values(reference):
     db = reference[0].refdb
-    mesh = sharding.make_profile_mesh(1)
+    mesh = make_profile_mesh(1)
     placed = place_refdb(db, mesh)
     np.testing.assert_array_equal(np.asarray(placed.prototypes),
                                   np.asarray(db.prototypes))

@@ -1,5 +1,5 @@
 """End-to-end system tests: the five-step Demeter pipeline on a synthetic
-food sample, FASTA/FASTQ IO, and dry-run harness internals."""
+food sample, FASTA/FASTQ IO, and the write-once RefDB."""
 
 import numpy as np
 import pytest
@@ -59,38 +59,3 @@ def test_refdb_is_write_once():
     db = dm.build_refdb({"a": rng.integers(0, 4, 2000).astype(np.int32)})
     with pytest.raises(dataclasses.FrozenInstanceError):
         db.prototypes = None
-
-
-def test_collective_parser():
-    from repro.launch import dryrun
-    hlo = """
-  %all-reduce = f32[1024]{0} all-reduce(%x), channel_id=1, replica_groups=[4,8]<=[32], use_global_device_ids=true
-  %ag = bf16[64,128]{1,0} all-gather(%y), channel_id=2, replica_groups=[2,16]<=[32]
-  %cp = bf16[32]{0} collective-permute(%z), channel_id=3
-  %other = f32[8]{0} add(%a, %b)
-"""
-    out = dryrun.parse_collectives(hlo)
-    assert out["all-reduce"]["count"] == 1
-    assert out["all-reduce"]["result_bytes"] == 4096
-    assert out["all-gather"]["count"] == 1
-    assert out["all-gather"]["result_bytes"] == 64 * 128 * 2
-    assert out["collective-permute"]["result_bytes"] == 64
-    # all-reduce link bytes = 2 * size * (g-1)/g with g=8
-    assert abs(out["all-reduce"]["link_bytes"] - 2 * 4096 * 7 / 8) < 1e-6
-    assert out["total_link_bytes"] > 0
-
-
-def test_dryrun_artifacts_if_present():
-    """Integration evidence: if the sweep ran, every cell must be ok."""
-    import json
-    import pathlib
-    art = pathlib.Path(__file__).parent.parent / "artifacts" / "dryrun"
-    files = sorted(art.glob("*.json")) if art.exists() else []
-    if not files:
-        pytest.skip("dry-run artifacts not generated in this environment")
-    bad = []
-    for f in files:
-        d = json.loads(f.read_text())
-        if not d["ok"]:
-            bad.append((f.name, d["error"][:100]))
-    assert not bad, bad
